@@ -417,7 +417,7 @@ def bench_sharded_peel(
     out_json="BENCH_dist.json",
 ) -> list[Row]:
     """Dist plane: bulk peel + incremental tick, single device vs an
-    n-device edge-sharded mesh (forced CPU host devices; ratios transfer).
+    n-device edge-sharded mesh.
     Writes ``out_json`` so the perf trajectory is recorded per commit."""
     import json
 
@@ -433,9 +433,15 @@ def bench_sharded_peel(
         sharded_insert_and_maintain,
     )
     from repro.graphstore.structs import device_graph_from_coo
+    from repro.launch.mesh import make_mesh
 
-    nd = min(n_devices, len(jax.devices()))
-    mesh = jax.make_mesh((nd,), ("data",))
+    if len(jax.devices()) < n_devices:
+        raise ValueError(
+            f"bench_sharded_peel asks for {n_devices} devices; "
+            f"{len(jax.devices())} exist"
+        )
+    nd = n_devices
+    mesh = make_mesh((nd,), ("data",))
     rng = np.random.default_rng(seed)
     src = rng.integers(0, n, m)
     dst = rng.integers(0, n, m)

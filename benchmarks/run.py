@@ -3,6 +3,9 @@
     PYTHONPATH=src python -m benchmarks.run [--quick]
 
 Prints ``name,us_per_call,derived`` CSV (derived: speedup/ratio per row).
+Every row runs in this one process.  The sharded rows use every device
+the backend has; on the CPU backend (``JAX_PLATFORMS=cpu``) the host is
+first split into ``--devices`` forced host devices.
 The roofline/dry-run artifacts are produced separately by
 ``repro.launch.dryrun`` and ``benchmarks.roofline`` (multi-process, 512
 host devices) and assembled by ``benchmarks.report``.
@@ -14,17 +17,41 @@ import argparse
 import os
 
 
+def _force_host_devices(n: int) -> None:
+    """Split the CPU backend into ``n`` devices; must precede jax backend
+    init.  Accelerator platforms are left as they are."""
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] != "cpu":
+        return
+    if "xla_force_host_platform_device_count" in os.environ.get("XLA_FLAGS", ""):
+        return
+    os.environ["XLA_FLAGS"] = (
+        f"--xla_force_host_platform_device_count={n} "
+        + os.environ.get("XLA_FLAGS", "")
+    ).strip()
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true", help="smaller graphs")
     ap.add_argument("--devices", type=int, default=8,
-                    help="forced XLA host devices for the sharded rows")
+                    help="forced host devices on the CPU backend")
     ap.add_argument("--sharded-only", action="store_true",
                     help="only the dist-plane rows (BENCH_dist.json)")
     ap.add_argument("--workset-only", action="store_true",
                     help="only the workset-engine rows (BENCH_workset.json; "
                          "the CI smoke lane)")
     args = ap.parse_args()
+
+    _force_host_devices(args.devices)
+    import jax
+
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+    from benchmarks.paper_tables import bench_sharded_peel
+
+    skw = dict(n=20_000, m=80_000) if args.quick else {}
+    skw["n_devices"] = len(jax.devices())
 
     rows = []
     if args.workset_only:
@@ -34,16 +61,7 @@ def main() -> None:
                 if args.quick else {})
         rows += bench_workset(**wskw)
     elif args.sharded_only:
-        # must precede jax backend init (first jax.devices() call below)
-        if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-            os.environ["XLA_FLAGS"] = (
-                f"--xla_force_host_platform_device_count={args.devices} "
-                + os.environ.get("XLA_FLAGS", "")
-            ).strip()
-        from benchmarks.paper_tables import bench_sharded_peel
-
-        skw = dict(n=20_000, m=80_000) if args.quick else {}
-        rows += bench_sharded_peel(n_devices=args.devices, **skw)
+        rows += bench_sharded_peel(**skw)
     else:
         from benchmarks.paper_tables import (
             bench_device_plane,
@@ -56,30 +74,13 @@ def main() -> None:
 
         kw = dict(n=4000, m=20000, n_inc=600) if args.quick else {}
         rows += bench_incremental_speedup(**kw)
-        rows += bench_edge_grouping(**({"n": 4000, "m": 20000, "n_inc": 600} if args.quick else {}))
+        rows += bench_edge_grouping(**kw)
         rows += bench_prevention()
         rows += bench_device_plane()
         wkw = dict(n=20_000, m=80_000, batch=512, window=4) if args.quick else {}
         rows += bench_window(**wkw)
         rows += bench_workset(**wkw)
-        # sharded rows run in a subprocess: the forced multi-device
-        # topology must not contaminate the legacy single-device rows
-        # (this backend is already initialized single-device by now)
-        import subprocess
-        import sys
-
-        cmd = [sys.executable, "-m", "benchmarks.run", "--sharded-only",
-               "--devices", str(args.devices)]
-        if args.quick:
-            cmd.append("--quick")
-        res = subprocess.run(cmd, capture_output=True, text=True)
-        if res.returncode != 0:
-            raise SystemExit(f"sharded benchmark subprocess failed:\n{res.stderr}")
-        for line in res.stdout.strip().splitlines():
-            if line.startswith("name,") or not line.strip():
-                continue
-            name, us, derived = line.split(",")
-            rows.append((name, float(us), float(derived)))
+        rows += bench_sharded_peel(**skw)
 
     print("name,us_per_call,derived")
     for name, us, derived in rows:

@@ -11,7 +11,8 @@ definition runs through
 
 * the single-device sliding-window engine,
 * the affected-area workset engine with the predictive bucket selector,
-* the mesh-sharded engine (8 forced CPU host devices),
+* the mesh-sharded engine over every device of the host (8 forced host
+  devices on the CPU backend),
 
 with **zero engine-file edits** — the hooks are compiled at the protocol
 boundary (``seed_base`` / ``batch_weights``), never dispatched by name
@@ -22,7 +23,8 @@ inside an engine.
 
 import os
 
-# mesh plane below wants 8 host devices; must be set before jax init
+# on the CPU backend the mesh plane below runs on 8 forced host devices;
+# must be set before jax init (accelerator backends ignore the flag)
 if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (
         "--xla_force_host_platform_device_count=8 "
@@ -33,6 +35,7 @@ import jax
 
 from repro.core.semantics import SuspSemantics, register
 from repro.graphstore.generators import make_transaction_stream
+from repro.launch.mesh import make_mesh
 from repro.serve import EngineSpec, SpadeService
 
 stream = make_transaction_stream(n=5000, m=25000, seed=12)
@@ -60,7 +63,8 @@ TDW = register(SuspSemantics(name="TDW", esusp=_decayed_amount, uses_aux=True))
 # the same semantics through three engines (and DW as the undecayed control)
 # ---------------------------------------------------------------------------
 
-mesh = jax.make_mesh((8,), ("data",))
+n_dev = len(jax.devices())
+mesh = make_mesh((n_dev,), ("data",))
 CONFIGS = [
     ("DW window-4", "DW",
      EngineSpec(batch_edges=512, max_rounds=20, refresh_every=16,
@@ -71,7 +75,7 @@ CONFIGS = [
     ("TDW workset-4", "TDW",
      EngineSpec(batch_edges=512, max_rounds=20, refresh_every=16,
                 window_ticks=4, workset=True, predictive=True)),
-    ("TDW mesh-8", "TDW",
+    (f"TDW mesh-{n_dev}", "TDW",
      EngineSpec(batch_edges=512, max_rounds=20, refresh_every=16,
                 window_ticks=4, mesh=mesh)),
 ]

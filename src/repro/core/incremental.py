@@ -37,6 +37,7 @@ from repro.core.peel import (
     bulk_peel_warm,
     bulk_peel_warm_checked,
     bulk_peel_warm_workset,
+    div_rn,
     select_bucket,
     workset_sizes,
 )
@@ -153,7 +154,7 @@ def _slide_prologue(
     # under-reports, never hides fraud); deletion may legally regress it
     n_comm = jnp.sum(state.community).astype(jnp.float32)
     prior_g = jnp.where(
-        n_comm > 0, state.best_g - comm_loss / jnp.maximum(n_comm, 1.0),
+        n_comm > 0, state.best_g - div_rn(comm_loss, jnp.maximum(n_comm, 1.0)),
         -jnp.float32(jnp.inf),
     )
     return _SlideBookkeeping(

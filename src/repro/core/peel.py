@@ -37,6 +37,7 @@ from repro.kernels.peel_round.ops import peel_round
 
 __all__ = [
     "PeelResultDevice",
+    "div_rn",
     "exact_peel",
     "bulk_peel",
     "bulk_peel_warm",
@@ -90,7 +91,7 @@ def exact_peel(g: DeviceGraph) -> PeelResultDevice:
         u = jnp.argmin(key)  # ties -> lowest id (matches host oracle)
         wu = key[u]
         # density of the set *before* this peel
-        g_cur = jnp.where(n_act > 0, f / jnp.maximum(n_act, 1), -_INF)
+        g_cur = jnp.where(n_act > 0, div_rn(f, jnp.maximum(n_act, 1)), -_INF)
         improved = g_cur > best_g
         best_g = jnp.where(improved, g_cur, best_g)
         best_i = jnp.where(improved, i, best_i)
@@ -154,6 +155,85 @@ class _BulkState(NamedTuple):
     round_: jax.Array
 
 
+def _split(x):
+    """Veltkamp split of an f32 into two halves of at most 12 bits."""
+    t = 4097.0 * x
+    hi = t - (t - x)
+    return hi, x - hi
+
+
+def _two_prod(x, y):
+    """(p, e) with p = fl(x * y) and p + e == x * y exactly (Dekker)."""
+    p = x * y
+    xh, xl = _split(x)
+    yh, yl = _split(y)
+    return p, ((xh * yh - p) + xh * yl + xl * yh) + xl * yl
+
+
+def _two_sum(x, y):
+    """(s, t) with s = fl(x + y) and s + t == x + y exactly (Knuth)."""
+    s = x + y
+    z = s - x
+    return s, (x - (s - z)) + (y - z)
+
+
+def _sign_diff3(d, h, e):
+    """Exact sign of ``d - h - e``.  The three terms are multiples of one
+    power of two and below 2^28 of it, so the rounding errors left after
+    two TwoSums are small integers of that unit and one more add is
+    exact, or cannot flip the sign."""
+    s, t = _two_sum(d, -h)
+    y, ye = _two_sum(s, -e)
+    return jnp.sign(y + (t + ye))
+
+
+def _div_rn_step(a, b, q):
+    """Move ``q`` one ulp towards RN(a / b): compare ``a`` with the two
+    midpoints ``(q +- ulp / 2) * b`` through an exact residual."""
+    bits = jax.lax.bitcast_convert_type(q, jnp.int32)
+    up = jax.lax.bitcast_convert_type(bits + 1, jnp.float32)
+    dn = jax.lax.bitcast_convert_type(bits - 1, jnp.float32)
+    p, e = _two_prod(q, b)
+    d = a - p  # exact: p is within a factor 2 of a (Sterbenz)
+    s_up = _sign_diff3(d, (up - q) * 0.5 * b, e)  # sign(a - m_up * b)
+    s_dn = _sign_diff3(d, (dn - q) * 0.5 * b, e)  # sign(a - m_dn * b)
+    even = (bits & 1) == 0
+    q = jnp.where((s_up > 0) | ((s_up == 0) & ~even), up, q)
+    return jnp.where((s_dn < 0) | ((s_dn == 0) & ~even), dn, q)
+
+
+def div_rn(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a / b`` in f32, rounded to nearest-even on every backend.
+
+    XLA:TPU divides through a reciprocal and can land one ulp off the
+    IEEE quotient the CPU returns, so a density and every threshold built
+    on it would differ between the chip and the CPU.  Here the hardware
+    quotient is corrected with exact residuals (add and multiply only,
+    which both backends round alike).  For finite normal ``a`` and
+    ``b > 0``, as densities ``f / n`` are.
+    """
+    a = jnp.asarray(a, jnp.float32)
+    b = jnp.asarray(b, jnp.float32)
+    m = jnp.abs(a)
+    q = m / b
+    q = _div_rn_step(m, b, _div_rn_step(m, b, q))
+    return jnp.where(m == 0, a / b, jnp.where(a < 0, -q, q))
+
+
+def _set_mass(active, a, edge_alive, c) -> jax.Array:
+    """f(S) of the surviving set, summed afresh every round.
+
+    Carrying f by subtraction (``f -= peeled mass``) starts from the whole
+    graph's mass; above 2^24 (a Grab4-scale graph under DG) f32 rounds
+    those steps in whatever order the reduction runs, and the error
+    survives into the small dense set whose density is reported.  A fresh
+    sum costs the same two masked reductions and is exact, in any order
+    and on any number of shards, once the set's integer mass fits f32.
+    """
+    return jnp.sum(jnp.where(active, a, 0.0)) + jnp.sum(
+        jnp.where(edge_alive, c, 0.0))
+
+
 def _round_step(
     src: jax.Array,
     dst: jax.Array,
@@ -182,7 +262,7 @@ def _round_step(
     by the production round rather than staying interpret-only dead code.
     """
     V = s.w.shape[0]
-    g_cur = s.f / jnp.maximum(s.n_act, 1).astype(jnp.float32)
+    g_cur = div_rn(s.f, jnp.maximum(s.n_act, 1))
     improved = (g_cur > s.best_g) & (s.n_act > 0)
     best_g = jnp.where(improved, g_cur, s.best_g)
     best_level = jnp.where(improved, s.round_, s.best_level)
@@ -190,9 +270,10 @@ def _round_step(
     thresh = 2.0 * (1.0 + eps) * g_cur
     peel = s.active & (s.w <= thresh)
     # progress guarantee: avg_u w_u <= 2 g(S), so the min-weight vertex
-    # always peels *in exact arithmetic*.  Under f32 the running f can
-    # drift slightly negative on a nearly-drained set, pushing the
-    # threshold below every remaining weight and stalling the while_loop;
+    # always peels *in exact arithmetic*.  Under f32 the running weights
+    # (carried by subtraction) can drift above f on a nearly-drained set,
+    # pushing the threshold below every remaining weight and stalling the
+    # while_loop;
     # force-peel the min-weight vertices then (a no-op whenever the
     # threshold test already fired, hence invisible on integer weights).
     wmin = jnp.min(jnp.where(s.active, s.w, _INF))
@@ -201,33 +282,30 @@ def _round_step(
     e_ps = peel[src]
     e_pd = peel[dst]
     cm = jnp.where(s.edge_alive, c, 0.0)
-    # every edge with >= 1 peeled endpoint leaves the restricted set
-    drop_mass = jnp.sum(jnp.where(e_ps | e_pd, cm, 0.0))
     # survivors lose suspiciousness of edges to peeled endpoints (the
     # round's SpMV: segment-sum form of the gather_segsum primitive)
     dw = jax.ops.segment_sum(
         jnp.where(e_ps & ~e_pd, cm, 0.0), dst, num_segments=V
     ) + jax.ops.segment_sum(jnp.where(e_pd & ~e_ps, cm, 0.0), src, num_segments=V)
+    # every edge with >= 1 peeled endpoint leaves the restricted set
+    edge_alive = s.edge_alive & ~(e_ps | e_pd)
     if use_kernel:
         # fused elementwise half: recomputes the same peel mask from
         # eff_thresh and applies the state update in one VMEM pass
         w, active, level, _, partials = peel_round(
             s.w, a, s.active, s.level, dw, eff_thresh, s.round_
         )
-        f = s.f - partials[0] - drop_mass
         n_act = s.n_act - partials[2].astype(jnp.int32)
     else:
         w = s.w - dw
         active = s.active & ~peel
         level = jnp.where(peel, s.round_, s.level)
-        # f loses peeled vertex weight + the dropped edge mass
-        f = s.f - jnp.sum(jnp.where(peel, a, 0.0)) - drop_mass
         n_act = s.n_act - jnp.sum(peel)
     return _BulkState(
         w=w,
         active=active,
-        edge_alive=s.edge_alive & ~(e_ps | e_pd),
-        f=f,
+        edge_alive=edge_alive,
+        f=_set_mass(active, a, edge_alive, c),
         n_act=n_act,
         level=level,
         best_g=best_g,

@@ -56,7 +56,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.incremental import (
@@ -71,6 +71,7 @@ from repro.core.peel import (
     PeelResultDevice,
     _compact_workset,
     _run_rounds,
+    div_rn,
     select_bucket,
 )
 from repro.graphstore.structs import DeviceGraph, compact_slots, remove_edges
@@ -163,7 +164,7 @@ def _shard_round(axis, eps, src, dst, c, a, s: _ShardState) -> _ShardState:
     (``s.w`` etc.) is replicated; edge arrays are a shard's local block
     (full buffers or a gathered workset alike)."""
     V = s.w.shape[0]
-    g_cur = s.f / jnp.maximum(s.n_act, 1).astype(jnp.float32)
+    g_cur = div_rn(s.f, jnp.maximum(s.n_act, 1))
     improved = (g_cur > s.best_g) & (s.n_act > 0)
     best_g = jnp.where(improved, g_cur, s.best_g)
     best_level = jnp.where(improved, s.round_, s.best_level)
@@ -181,13 +182,16 @@ def _shard_round(axis, eps, src, dst, c, a, s: _ShardState) -> _ShardState:
     ) + jax.ops.segment_sum(
         jnp.where(e_pd & ~e_ps, cm, 0.0), src, num_segments=V
     )
-    drop_l = jnp.sum(jnp.where(e_ps | e_pd, cm, 0.0))
-    dw, drop = jax.lax.psum((dw_l, drop_l), axis)
+    edge_alive = s.edge_alive & ~(e_ps | e_pd)
+    mass_l = jnp.sum(jnp.where(edge_alive, c, 0.0))
+    dw, e_mass = jax.lax.psum((dw_l, mass_l), axis)
+    active = s.active & ~peel
+    # f afresh from the survivors, as core.peel._set_mass does
     return _ShardState(
         w=s.w - dw,
-        active=s.active & ~peel,
-        edge_alive=s.edge_alive & ~(e_ps | e_pd),
-        f=s.f - jnp.sum(jnp.where(peel, a, 0.0)) - drop,
+        active=active,
+        edge_alive=edge_alive,
+        f=jnp.sum(jnp.where(active, a, 0.0)) + e_mass,
         n_act=s.n_act - jnp.sum(peel),
         level=jnp.where(peel, s.round_, s.level),
         best_g=best_g,
@@ -252,7 +256,7 @@ def _sharded_peel(
         mesh=mesh,
         in_specs=(es, es, es, es, rs, rs, rs, rs),
         out_specs=(rs,) * 5,
-        check_rep=False,
+        check_vma=False,
     )(g.src, g.dst, g.c, g.edge_mask, g.a, g.vertex_mask, keep, prior_g)
     return PeelResultDevice(
         level=level,
@@ -374,7 +378,7 @@ def sharded_bulk_peel_warm_workset(
         mesh=mesh,
         in_specs=(es, es, es, es, rs, rs, rs, rs),
         out_specs=(rs,) * 5,
-        check_rep=False,
+        check_vma=False,
     )(g.src, g.dst, g.c, g.edge_mask, g.a, g.vertex_mask, keep, prior_best_g)
     return PeelResultDevice(
         level=level,
@@ -403,7 +407,7 @@ def sharded_workset_sizes(
     es, rs = P(axis), P()
     return shard_map(
         fn, mesh=mesh, in_specs=(es, es, es, es, rs, rs), out_specs=(rs, rs),
-        check_rep=False,
+        check_vma=False,
     )(g.src, g.dst, g.c, g.edge_mask, g.vertex_mask, keep)
 
 
@@ -423,7 +427,7 @@ def sharded_peel_weights(g: DeviceGraph, mesh: Mesh, axis: str = "data") -> jax.
     es, rs = P(axis), P()
     return shard_map(
         fn, mesh=mesh, in_specs=(es, es, es, es, rs, rs), out_specs=rs,
-        check_rep=False,
+        check_vma=False,
     )(g.src, g.dst, g.c, g.edge_mask, g.a, g.vertex_mask)
 
 
@@ -465,7 +469,7 @@ def _sharded_append(
         mesh=mesh,
         in_specs=(es, es, es, es, rs, rs, rs, rs, rs),
         out_specs=(es,) * 4,
-        check_rep=False,
+        check_vma=False,
     )(g.src, g.dst, g.c, g.edge_mask, src, dst, c, valid, offset)
     return dataclasses.replace(g, src=nsrc, dst=ndst, c=nc, edge_mask=nmask)
 

@@ -23,6 +23,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from repro.launch.mesh import make_mesh
+
 __all__ = ["best_mesh_for", "StepWatchdog", "ElasticPlan", "replan"]
 
 
@@ -38,7 +40,7 @@ def best_mesh_for(
     devs = np.asarray(devices if devices is not None else jax.devices())[
         : data * model_axis
     ]
-    return Mesh(devs.reshape(data, model_axis), axis_names)
+    return make_mesh((data, model_axis), axis_names, devices=devs)
 
 
 @dataclasses.dataclass

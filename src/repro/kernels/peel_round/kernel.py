@@ -13,9 +13,11 @@ the vertex arrays instead of four XLA elementwise kernels:
     level'     = peeled ? round : level
     partials   = [sum(peeled a), sum(peeled w), n_peeled]   (for f/n update)
 
-Grid: vertex tiles of 8*128 lanes; partial reductions land in a small
-output accumulated on the host side of the call (one scalar triple per
-tile).  Validated in interpret mode against ``ref.py``.
+Grid: vertex tiles of ``block`` lanes.  Each tile writes its partial-sum
+triple into lanes 0..2 of row 0 of one (8, 128) f32 output tile, the
+smallest block the TPU's tiling admits, and the wrapper keeps one triple
+per tile.  Validated in interpret mode against ``ref.py``; compiled for
+a v5e chip in ``tests/test_chip_compile.py``.
 """
 
 from __future__ import annotations
@@ -29,6 +31,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["peel_round_update"]
 
+_PART_TILE = (8, 128)
+
 
 def _kernel(w_ref, a_ref, active_ref, level_ref, dw_ref, thresh_ref, round_ref,
             w_out, active_out, level_out, peeled_out, partial_out):
@@ -41,9 +45,14 @@ def _kernel(w_ref, a_ref, active_ref, level_ref, dw_ref, thresh_ref, round_ref,
     active_out[...] = jnp.logical_and(active, jnp.logical_not(peeled))
     level_out[...] = jnp.where(peeled, round_ref[0], level_ref[...])
     peeled_out[...] = peeled
-    partial_out[0, 0] = jnp.sum(pf * a_ref[...])
-    partial_out[0, 1] = jnp.sum(pf * w)
-    partial_out[0, 2] = jnp.sum(pf)
+    # the tile's three partial sums in lanes 0..2 of row 0 of an (8, 128)
+    # tile: the smallest f32 block the TPU's (8, 128) tiling admits
+    row = jax.lax.broadcasted_iota(jnp.int32, _PART_TILE, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, _PART_TILE, 1)
+    tile = jnp.where(lane == 0, jnp.sum(pf * a_ref[...]), 0.0)
+    tile = jnp.where(lane == 1, jnp.sum(pf * w), tile)
+    tile = jnp.where(lane == 2, jnp.sum(pf), tile)
+    partial_out[...] = jnp.where(row == 0, tile, 0.0)
 
 
 def peel_round_update(
@@ -79,16 +88,18 @@ def peel_round_update(
         in_specs=[vec(), vec(), vec(), vec(), vec(), scl(), scl()],
         out_specs=[
             vec(), vec(), vec(), vec(),
-            pl.BlockSpec((1, 3), lambda i: (i, 0)),
+            pl.BlockSpec(_PART_TILE, lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((nb * block,), jnp.float32),
             jax.ShapeDtypeStruct((nb * block,), jnp.bool_),
             jax.ShapeDtypeStruct((nb * block,), jnp.int32),
             jax.ShapeDtypeStruct((nb * block,), jnp.bool_),
-            jax.ShapeDtypeStruct((nb, 3), jnp.float32),
+            jax.ShapeDtypeStruct((nb * _PART_TILE[0], _PART_TILE[1]),
+                                 jnp.float32),
         ],
         interpret=interpret,
     )(w, a, active, level, dw, thresh, round_)
     w2, active2, level2, peeled, partials = outs
+    partials = partials.reshape(nb, *_PART_TILE)[:, 0, :3]
     return w2[:V], active2[:V], level2[:V], peeled[:V], partials

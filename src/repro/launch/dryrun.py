@@ -1,5 +1,8 @@
 import os
 
+# a CPU tool: lowers against 512 forced host devices and never takes an
+# accelerator, even on a host that has one
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS_EXTRA", "")
@@ -117,8 +120,6 @@ def run_cell(arch: str, shape: str, mesh_kind: str, verbose: bool = True,
             t_compile = time.time() - t0 - t_lower
             mem = compiled.memory_analysis()
             cost = compiled.cost_analysis()
-            if isinstance(cost, (list, tuple)):  # older jax: [dict]
-                cost = cost[0] if cost else {}
             hlo = compiled.as_text()
             coll = collective_bytes(hlo)
         n_chips = int(np.prod(mesh.devices.shape))

@@ -21,6 +21,8 @@ import argparse
 
 from repro.core.semantics import available
 from repro.graphstore.generators import make_transaction_stream
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.serve import EngineSpec, SpadeService
 
 
@@ -52,6 +54,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    use_compile_cache()
     stream = make_transaction_stream(n=args.vertices, m=args.edges,
                                      seed=args.seed)
     if args.plane == "host":
@@ -71,9 +74,7 @@ def main() -> None:
     else:
         mesh = None
         if args.mesh:
-            import jax
-
-            mesh = jax.make_mesh((args.mesh,), ("data",))
+            mesh = make_mesh((args.mesh,), ("data",))
         spec = EngineSpec(
             plane="device",
             mesh=mesh,

@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MoESpec
@@ -82,7 +82,7 @@ def moe_ffn(x: jax.Array, p: dict, spec: MoESpec) -> tuple[jax.Array, jax.Array]
         buf = shard_map(
             scatter_blocks, mesh=env.mesh,
             in_specs=(P(bx, None, None), P(bx, None)),
-            out_specs=P(bx, None, None), check_rep=False,
+            out_specs=P(bx, None, None), check_vma=False,
         )(xb, slot)
     else:
         buf = scatter_blocks(xb, slot)
@@ -126,7 +126,7 @@ def moe_ffn(x: jax.Array, p: dict, spec: MoESpec) -> tuple[jax.Array, jax.Array]
         out = shard_map(
             gather_blocks, mesh=env.mesh,
             in_specs=(P(bx, None, None), P(bx, None), P(bx, None)),
-            out_specs=P(bx, None, None), check_rep=False,
+            out_specs=P(bx, None, None), check_vma=False,
         )(y, slot, gk)
     else:
         out = gather_blocks(y, slot, gk)

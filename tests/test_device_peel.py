@@ -15,7 +15,13 @@ from repro.core.incremental import (
     init_state,
     insert_and_maintain,
 )
-from repro.core.peel import bulk_peel, bulk_peel_warm, exact_peel
+from repro.core.peel import (
+    _div_rn_step,
+    bulk_peel,
+    bulk_peel_warm,
+    div_rn,
+    exact_peel,
+)
 from repro.core.reference import AdjGraph, detect, static_peel
 from repro.graphstore.structs import device_graph_from_coo
 
@@ -34,6 +40,56 @@ def random_coo(rng, n, m, int_weights=True):
 
 def to_oracle(n, src, dst, c, a):
     return AdjGraph.from_arrays(n, src, dst, c, a)
+
+
+# ---------------------------------------------------------------------------
+# densities: f32 division rounded to nearest on every backend
+# ---------------------------------------------------------------------------
+
+
+def _division_cases(rng, n):
+    """Integer masses over counts, floats over wide exponents, and
+    numerators rounded from exact midpoints between two floats."""
+    q = (rng.random(n) * 1000 + 1).astype(np.float32)
+    mid = (q.astype(np.float64)
+           + np.nextafter(q, np.float32(np.inf)).astype(np.float64)) / 2
+    b3 = rng.integers(1, 2**12, n).astype(np.float32)
+    a = np.concatenate([
+        rng.integers(1, 2**24, n).astype(np.float32),
+        (rng.random(n) * 2.0 ** rng.integers(-20, 40, n)).astype(np.float32),
+        np.round(mid * b3.astype(np.float64)).astype(np.float32),
+        -rng.integers(0, 2**24, n).astype(np.float32),
+    ])
+    b = np.concatenate([
+        rng.integers(1, 2**23, n).astype(np.float32),
+        rng.integers(1, 2**31, n).astype(np.float32),
+        b3,
+        rng.integers(1, 2**23, n).astype(np.float32),
+    ])
+    return a, b
+
+
+@pytest.mark.parametrize("hw_error_ulps", [0, 1])
+def test_div_rn_is_the_ieee_quotient(hw_error_ulps):
+    """``div_rn`` returns numpy's (IEEE) f32 quotient bit for bit, also
+    when the hardware quotient it starts from is an ulp off either way,
+    as XLA:TPU's can be."""
+    rng = np.random.default_rng(hw_error_ulps)
+    a, b = _division_cases(rng, 50_000)
+    want = a / b
+    if hw_error_ulps == 0:
+        got = jax.jit(div_rn)(a, b)
+    else:
+        m, w = np.abs(a), np.abs(want)
+        k = rng.integers(-1, 2, a.size)
+        q0 = np.where(k > 0, np.nextafter(w, np.float32(np.inf)),
+                      np.where(k < 0, np.nextafter(w, np.float32(0)), w))
+        q0 = np.where(m == 0, w, q0).astype(np.float32)
+        got = jax.jit(lambda a, b, q: _div_rn_step(a, b, _div_rn_step(
+            a, b, q)))(m, b, q0)
+        want = w
+    np.testing.assert_array_equal(np.asarray(got).view(np.int32),
+                                  want.view(np.int32))
 
 
 # ---------------------------------------------------------------------------

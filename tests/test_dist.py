@@ -42,6 +42,7 @@ from repro.dist.sharding import (
     use_axis_env,
 )
 from repro.graphstore.structs import device_graph_from_coo
+from repro.launch.mesh import make_mesh
 
 multi_device = pytest.mark.skipif(
     len(jax.devices()) < 2, reason="needs >=2 XLA host devices"
@@ -49,7 +50,7 @@ multi_device = pytest.mark.skipif(
 
 
 def data_mesh(n: int):
-    return jax.make_mesh((n,), ("data",))
+    return make_mesh((n,), ("data",))
 
 
 def random_graph(seed: int, n: int = 200, m: int = 900, e_slack: int = 512):
@@ -77,7 +78,7 @@ def test_constrain_is_noop_without_env():
 
 @multi_device
 def test_axis_env_resolution_and_constrain():
-    mesh = jax.make_mesh((2, len(jax.devices()) // 2), ("data", "model"))
+    mesh = make_mesh((2, len(jax.devices()) // 2), ("data", "model"))
     env = AxisEnv(mesh=mesh)
     # 'pod' absent -> batch lands on data alone; expert rides model
     assert env.resolve("batch") == "data"
@@ -177,6 +178,73 @@ def test_sharded_bulk_peel_two_way_mesh():
     ref = bulk_peel(g, eps=0.1)
     assert float(res.best_g) == float(ref.best_g)
     np.testing.assert_array_equal(np.asarray(res.level), np.asarray(ref.level))
+
+
+@multi_device
+@pytest.mark.parametrize("n_shards,p_drop", [
+    (2, 0.05), (4, 0.5), (8, 0.0), (8, 0.05), (8, 0.95), (8, 1.0),
+])
+def test_sharded_remove_matches_remove_edges(n_shards, p_drop):
+    """The sharded compaction is slot-for-slot ``remove_edges`` and its
+    output stays on the edge axis, also when the drops exceed a shard's
+    block and survivors cross several shard boundaries."""
+    from repro.dist.graph import _sharded_remove
+    from repro.graphstore.structs import remove_edges
+
+    g = random_graph(n_shards, e_slack=37)
+    mesh = data_mesh(n_shards)
+    gs = shard_graph(g, mesh)
+    rng = np.random.default_rng(int(100 * p_drop))
+    drop = jnp.asarray(rng.random(gs.e_capacity) < p_drop)
+    want, n_want = remove_edges(gs, drop)
+    got, n_got = jax.jit(_sharded_remove, static_argnums=(2, 3))(
+        gs, drop, mesh, "data")
+    assert int(n_got) == int(n_want)
+    assert got.src.sharding.spec == P("data")
+    for f in ("src", "dst", "c", "edge_mask"):
+        np.testing.assert_array_equal(np.asarray(getattr(got, f)),
+                                      np.asarray(getattr(want, f)), err_msg=f)
+
+
+@multi_device
+def test_sharded_density_exact_above_f32_integer_range():
+    """The graph's mass exceeds 2^24, where f32 sums of integers round in
+    reduction order; the densest set's mass does not.  Its density must be
+    exact and identical on one device and on a mesh, cold and warm."""
+    rng = np.random.default_rng(3)
+    n, m = 300, 2000
+    src, dst = rng.integers(10, n, m), rng.integers(10, n, m)
+    keep = src != dst
+    src, dst = src[keep], dst[keep]
+    c = np.full(src.shape[0], 10_001.0, np.float32)
+    bs, bd = np.triu_indices(10, 1)  # a 10-clique of heavy edges
+    src, dst = np.concatenate([src, bs]), np.concatenate([dst, bd])
+    c = np.concatenate([c, np.full(bs.shape[0], 300_007.0, np.float32)])
+    assert c.astype(np.float64).sum() > 2**24 > 300_007.0 * bs.shape[0]
+    g = device_graph_from_coo(n, src, dst, c, e_capacity=src.shape[0] + 64)
+    mesh = data_mesh(len(jax.devices()))
+
+    def exact_density(comm):
+        comm = np.asarray(comm)[:n]
+        mass = c.astype(np.float64)[comm[src] & comm[dst]].sum()
+        assert 0 < mass < 2**24
+        return float(np.float32(mass) / np.float32(comm.sum()))
+
+    ref = bulk_peel(g, eps=0.1)
+    res = sharded_bulk_peel(shard_graph(g, mesh), mesh, eps=0.1)
+    exact = exact_density(ref.community_mask())
+    assert float(ref.best_g) == float(res.best_g) == exact
+
+    st_ref = init_state(g, eps=0.1)
+    st_sh = init_sharded_state(shard_graph(g, mesh), mesh, eps=0.1)
+    b = jnp.asarray(rng.integers(10, n, (2, 64)), jnp.int32)
+    bc = jnp.full(64, 10_001.0, jnp.float32)
+    valid = b[0] != b[1]
+    st_ref = insert_and_maintain(st_ref, b[0], b[1], bc, valid, eps=0.1)
+    st_sh = sharded_insert_and_maintain(st_sh, b[0], b[1], bc, valid,
+                                        mesh=mesh, eps=0.1)
+    assert float(st_ref.best_g) == float(st_sh.best_g)
+    assert float(st_ref.best_g) == exact_density(st_ref.community)
 
 
 @multi_device

@@ -512,10 +512,11 @@ def test_custom_semantics_cross_plane_differential():
         sharded_insert_and_maintain_auto,
         sharded_slide_and_maintain,
     )
+    from repro.launch.mesh import make_mesh
 
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 (forced host) devices")
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     sem = PARITY_SEM
     rng = np.random.default_rng(1234)
 
