@@ -6,9 +6,8 @@ Prints ``name,us_per_call,derived`` CSV (derived: speedup/ratio per row).
 Every row runs in this one process.  The sharded rows use every device
 the backend has; on the CPU backend (``JAX_PLATFORMS=cpu``) the host is
 first split into ``--devices`` forced host devices.
-The roofline/dry-run artifacts are produced separately by
-``repro.launch.dryrun`` and ``benchmarks.roofline`` (multi-process, 512
-host devices) and assembled by ``benchmarks.report``.
+The dry-run artifacts are produced separately by ``repro.launch.dryrun``
+(512 host devices).
 """
 
 from __future__ import annotations

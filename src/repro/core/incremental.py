@@ -57,6 +57,7 @@ __all__ = [
     "slide_and_maintain_predictive",
     "full_refresh",
     "benign_mask",
+    "tick_counters",
 ]
 
 _LEVEL_NEW = jnp.int32(2**31 - 1)
@@ -228,7 +229,42 @@ def _slide_epilogue(
     )
 
 
-@partial(jax.jit, static_argnames=("eps", "max_rounds", "unroll"),
+def tick_counters(counts: np.ndarray, max_rounds: int) -> dict:
+    """Split the fused tick's counter vectors, stacked ``[n_ticks, 1 + 2 *
+    max_rounds]`` on the host, into named ``int64`` arrays.
+
+    ``suffix_r0``: the tick's affected-suffix start ``r0``;
+    ``round_vertices[t, i]`` / ``round_edges[t, i]``: active vertices and
+    live edges of the restricted set at the start of round ``i``.  Round 0
+    holds the whole suffix: ``suffix_vertices`` and ``suffix_edges`` (the
+    suffix's induced live edges) are its column.
+    """
+    counts = np.asarray(counts, np.int64).reshape(-1, 1 + 2 * max_rounds)
+    rv = counts[:, 1:1 + max_rounds]
+    re = counts[:, 1 + max_rounds:]
+    return {"suffix_r0": counts[:, 0], "suffix_vertices": rv[:, 0].copy(),
+            "suffix_edges": re[:, 0].copy(), "round_vertices": rv,
+            "round_edges": re}
+
+
+def _warm_and_merge(state, g, bk, n_removed, src, dst, c, valid, eps,
+                    max_rounds, unroll, counters, with_drops=True):
+    """The fused tick's warm re-peel and merge; with ``counters`` also its
+    int32 counter vector ``[r0, round_vertices..., round_edges...]``."""
+    res = bulk_peel_warm(g, bk.keep, prior_best_g=bk.prior_g, eps=eps,
+                         max_rounds=max_rounds, unroll=unroll,
+                         counters=counters)
+    if counters:
+        res, (rv, re) = res
+    with jax.named_scope("tick_merge"):
+        new = _slide_epilogue(state, g, res, bk, n_removed, src, dst, c,
+                              valid, with_drops=with_drops)
+    if not counters:
+        return new
+    return new, jnp.concatenate([bk.r0.astype(jnp.int32)[None], rv, re])
+
+
+@partial(jax.jit, static_argnames=("eps", "max_rounds", "unroll", "counters"),
          donate_argnames=("state",))
 def insert_and_maintain(
     state: DeviceSpadeState,
@@ -239,7 +275,8 @@ def insert_and_maintain(
     eps: float = 0.1,
     max_rounds: int = 0,
     unroll: bool = False,
-) -> DeviceSpadeState:
+    counters: bool = False,
+):
     """Insert an edge batch and maintain the community incrementally.
 
     ``src/dst/c`` are fixed-size batch arrays with a ``valid`` mask
@@ -250,12 +287,20 @@ def insert_and_maintain(
     slide that expires nothing — one definition for insert/delete/slide,
     so the three paths cannot drift); unlike the slide the live prefix is
     untouched, so the compaction pass is skipped entirely.
+
+    Returns the new state; with ``counters`` (a bounded peel only) returns
+    ``(state, counts)``, ``counts`` the tick's int32 counter vector from
+    the same program (:func:`tick_counters` names its parts).  The steps
+    sit under the named scopes ``tick_prologue``, ``tick_append``,
+    ``tick_seed``, ``tick_rounds`` and ``tick_merge``.
     """
-    bk = _slide_prologue(state, None, src, dst, valid)
-    g = append_edges(state.graph, state.edge_count, src, dst, c, valid=valid)
-    res = bulk_peel_warm(g, bk.keep, prior_best_g=bk.prior_g, eps=eps,
-                         max_rounds=max_rounds, unroll=unroll)
-    return _slide_epilogue(state, g, res, bk, jnp.int32(0), src, dst, c, valid,
+    with jax.named_scope("tick_prologue"):
+        bk = _slide_prologue(state, None, src, dst, valid)
+    with jax.named_scope("tick_append"):
+        g = append_edges(state.graph, state.edge_count, src, dst, c,
+                         valid=valid)
+    return _warm_and_merge(state, g, bk, jnp.int32(0), src, dst, c, valid,
+                           eps, max_rounds, unroll, counters,
                            with_drops=False)
 
 
@@ -291,7 +336,7 @@ def delete_and_maintain(
     )
 
 
-@partial(jax.jit, static_argnames=("eps", "max_rounds", "unroll"),
+@partial(jax.jit, static_argnames=("eps", "max_rounds", "unroll", "counters"),
          donate_argnames=("state",))
 def slide_and_maintain(
     state: DeviceSpadeState,
@@ -303,7 +348,8 @@ def slide_and_maintain(
     eps: float = 0.1,
     max_rounds: int = 0,
     unroll: bool = False,
-) -> DeviceSpadeState:
+    counters: bool = False,
+):
     """One fused sliding-window tick: expire ``drop``, insert the batch,
     re-peel **once** (paper Appendix C.3, vectorized).
 
@@ -316,13 +362,16 @@ def slide_and_maintain(
     mass, the best-density tracker is re-seeded with the old community's
     exact post-deletion density (DESIGN.md §6), and the edge counter
     shrinks by the dropped count and grows by the inserted count.
+    ``counters`` and the named scopes as in :func:`insert_and_maintain`.
     """
-    bk = _slide_prologue(state, drop, src, dst, valid)
-    g, n_removed = remove_edges(state.graph, drop)
-    g = append_edges(g, state.edge_count - n_removed, src, dst, c, valid=valid)
-    res = bulk_peel_warm(g, bk.keep, prior_best_g=bk.prior_g, eps=eps,
-                         max_rounds=max_rounds, unroll=unroll)
-    return _slide_epilogue(state, g, res, bk, n_removed, src, dst, c, valid)
+    with jax.named_scope("tick_prologue"):
+        bk = _slide_prologue(state, drop, src, dst, valid)
+    with jax.named_scope("tick_append"):
+        g, n_removed = remove_edges(state.graph, drop)
+        g = append_edges(g, state.edge_count - n_removed, src, dst, c,
+                         valid=valid)
+    return _warm_and_merge(state, g, bk, n_removed, src, dst, c, valid,
+                           eps, max_rounds, unroll, counters)
 
 
 # ---------------------------------------------------------------------------

@@ -260,52 +260,63 @@ def _round_step(
     (Pallas on TPU, pure-jnp reference elsewhere).  On integer weights the
     two paths are bit-identical; the flag exists so the kernel is exercised
     by the production round rather than staying interpret-only dead code.
+
+    The round's work sits under three named scopes, which the device
+    trace's ops carry in their ``op_name`` metadata: ``peel_gather`` (the
+    [V]-by-[E] gathers of the peel mask and the edge-liveness update),
+    ``peel_scatter`` (the two [E]-to-[V] segment sums, with the sorts XLA
+    adds for them) and ``peel_update`` (threshold, vertex update, f(S)).
     """
     V = s.w.shape[0]
-    g_cur = div_rn(s.f, jnp.maximum(s.n_act, 1))
-    improved = (g_cur > s.best_g) & (s.n_act > 0)
-    best_g = jnp.where(improved, g_cur, s.best_g)
-    best_level = jnp.where(improved, s.round_, s.best_level)
-
-    thresh = 2.0 * (1.0 + eps) * g_cur
-    peel = s.active & (s.w <= thresh)
-    # progress guarantee: avg_u w_u <= 2 g(S), so the min-weight vertex
-    # always peels *in exact arithmetic*.  Under f32 the running weights
-    # (carried by subtraction) can drift above f on a nearly-drained set,
-    # pushing the threshold below every remaining weight and stalling the
-    # while_loop;
-    # force-peel the min-weight vertices then (a no-op whenever the
-    # threshold test already fired, hence invisible on integer weights).
-    wmin = jnp.min(jnp.where(s.active, s.w, _INF))
-    eff_thresh = jnp.where(jnp.any(peel), thresh, wmin)
-    peel = jnp.where(jnp.any(peel), peel, s.active & (s.w <= wmin))
-    e_ps = peel[src]
-    e_pd = peel[dst]
-    cm = jnp.where(s.edge_alive, c, 0.0)
-    # survivors lose suspiciousness of edges to peeled endpoints (the
-    # round's SpMV: segment-sum form of the gather_segsum primitive)
-    dw = jax.ops.segment_sum(
-        jnp.where(e_ps & ~e_pd, cm, 0.0), dst, num_segments=V
-    ) + jax.ops.segment_sum(jnp.where(e_pd & ~e_ps, cm, 0.0), src, num_segments=V)
-    # every edge with >= 1 peeled endpoint leaves the restricted set
-    edge_alive = s.edge_alive & ~(e_ps | e_pd)
-    if use_kernel:
-        # fused elementwise half: recomputes the same peel mask from
-        # eff_thresh and applies the state update in one VMEM pass
-        w, active, level, _, partials = peel_round(
-            s.w, a, s.active, s.level, dw, eff_thresh, s.round_
-        )
-        n_act = s.n_act - partials[2].astype(jnp.int32)
-    else:
-        w = s.w - dw
-        active = s.active & ~peel
-        level = jnp.where(peel, s.round_, s.level)
-        n_act = s.n_act - jnp.sum(peel)
+    with jax.named_scope("peel_update"):
+        g_cur = div_rn(s.f, jnp.maximum(s.n_act, 1))
+        improved = (g_cur > s.best_g) & (s.n_act > 0)
+        best_g = jnp.where(improved, g_cur, s.best_g)
+        best_level = jnp.where(improved, s.round_, s.best_level)
+        thresh = 2.0 * (1.0 + eps) * g_cur
+        peel = s.active & (s.w <= thresh)
+        # progress guarantee: avg_u w_u <= 2 g(S), so the min-weight vertex
+        # always peels *in exact arithmetic*.  Under f32 the running weights
+        # (carried by subtraction) can drift above f on a nearly-drained
+        # set, pushing the threshold below every remaining weight and
+        # stalling the while_loop; force-peel the min-weight vertices then
+        # (a no-op whenever the threshold test already fired, hence
+        # invisible on integer weights).
+        wmin = jnp.min(jnp.where(s.active, s.w, _INF))
+        eff_thresh = jnp.where(jnp.any(peel), thresh, wmin)
+        peel = jnp.where(jnp.any(peel), peel, s.active & (s.w <= wmin))
+    with jax.named_scope("peel_gather"):
+        e_ps = peel[src]
+        e_pd = peel[dst]
+        # every edge with >= 1 peeled endpoint leaves the restricted set
+        edge_alive = s.edge_alive & ~(e_ps | e_pd)
+    with jax.named_scope("peel_scatter"):
+        cm = jnp.where(s.edge_alive, c, 0.0)
+        # survivors lose suspiciousness of edges to peeled endpoints (the
+        # round's SpMV: segment-sum form of the gather_segsum primitive)
+        dw = jax.ops.segment_sum(
+            jnp.where(e_ps & ~e_pd, cm, 0.0), dst, num_segments=V
+        ) + jax.ops.segment_sum(
+            jnp.where(e_pd & ~e_ps, cm, 0.0), src, num_segments=V)
+    with jax.named_scope("peel_update"):
+        if use_kernel:
+            # fused elementwise half: recomputes the same peel mask from
+            # eff_thresh and applies the state update in one VMEM pass
+            w, active, level, _, partials = peel_round(
+                s.w, a, s.active, s.level, dw, eff_thresh, s.round_
+            )
+            n_act = s.n_act - partials[2].astype(jnp.int32)
+        else:
+            w = s.w - dw
+            active = s.active & ~peel
+            level = jnp.where(peel, s.round_, s.level)
+            n_act = s.n_act - jnp.sum(peel)
+        f = _set_mass(active, a, edge_alive, c)
     return _BulkState(
         w=w,
         active=active,
         edge_alive=edge_alive,
-        f=_set_mass(active, a, edge_alive, c),
+        f=f,
         n_act=n_act,
         level=level,
         best_g=best_g,
@@ -374,6 +385,27 @@ def _run_rounds(round_fn, init, max_rounds: int, unroll: bool = False):
     return jax.lax.while_loop(lambda s: s.n_act > 0, round_fn, init)
 
 
+def _run_rounds_counted(round_fn, init, max_rounds: int, unroll: bool = False):
+    """:func:`_run_rounds` for a bounded peel that also counts, at the start
+    of every round, the restricted set's active vertices and live edges.
+
+    Returns ``(state, (round_vertices, round_edges))``, two int32
+    ``[max_rounds]`` vectors.  A round that starts with no active vertex
+    peels nothing: its entry reads 0.  The edge count adds one [E] bool
+    reduction per round.
+    """
+    if max_rounds <= 0:
+        raise ValueError("round counters need a bounded peel (max_rounds > 0)")
+
+    def step(s, _):
+        counts = (s.n_act.astype(jnp.int32),
+                  jnp.sum(s.edge_alive, dtype=jnp.int32))
+        return round_fn(s), counts
+
+    return jax.lax.scan(step, init, None, length=max_rounds,
+                        unroll=max_rounds if unroll else 1)
+
+
 def bulk_peel_warm(
     g: DeviceGraph,
     keep: jax.Array,
@@ -382,7 +414,8 @@ def bulk_peel_warm(
     max_rounds: int = 0,
     unroll: bool = False,
     use_kernel: bool = False,
-) -> PeelResultDevice:
+    counters: bool = False,
+):
     """Bulk peel restricted to ``keep`` vertices (warm start).
 
     Used by the incremental suffix re-peel: vertices outside ``keep`` are
@@ -397,15 +430,19 @@ def bulk_peel_warm(
     bucketed buffers first and is the steady-state serving path; this
     function remains the fallback when the suffix exceeds the largest
     bucket (DESIGN.md §8).
+
+    ``counters`` (a bounded peel only) returns ``(result, (round_vertices,
+    round_edges))``: see :func:`_run_rounds_counted`.
     """
     V = g.n_capacity
-    live = keep & g.vertex_mask
-    both = live[g.src] & live[g.dst] & g.edge_mask
-    cm = jnp.where(both, g.c, 0.0)
-    w0 = jnp.where(live, g.a, 0.0)
-    w0 = w0 + jax.ops.segment_sum(cm, g.src, num_segments=V)
-    w0 = w0 + jax.ops.segment_sum(cm, g.dst, num_segments=V)
-    f0 = jnp.sum(jnp.where(live, g.a, 0.0)) + jnp.sum(cm)
+    with jax.named_scope("tick_seed"):
+        live = keep & g.vertex_mask
+        both = live[g.src] & live[g.dst] & g.edge_mask
+        cm = jnp.where(both, g.c, 0.0)
+        w0 = jnp.where(live, g.a, 0.0)
+        w0 = w0 + jax.ops.segment_sum(cm, g.src, num_segments=V)
+        w0 = w0 + jax.ops.segment_sum(cm, g.dst, num_segments=V)
+        f0 = jnp.sum(jnp.where(live, g.a, 0.0)) + jnp.sum(cm)
 
     init = _BulkState(
         w=w0,
@@ -418,10 +455,14 @@ def bulk_peel_warm(
         best_level=jnp.int32(0),
         round_=jnp.int32(0),
     )
-    state = _run_rounds(
-        partial(_bulk_round, g, eps, use_kernel=use_kernel), init, max_rounds, unroll
-    )
-    return PeelResultDevice(
+    round_fn = partial(_bulk_round, g, eps, use_kernel=use_kernel)
+    with jax.named_scope("tick_rounds"):
+        if counters:
+            state, counts = _run_rounds_counted(round_fn, init, max_rounds,
+                                                unroll)
+        else:
+            state = _run_rounds(round_fn, init, max_rounds, unroll)
+    res = PeelResultDevice(
         level=state.level,
         best_level=state.best_level,
         best_g=state.best_g,
@@ -429,6 +470,7 @@ def bulk_peel_warm(
         order=jnp.zeros(V, jnp.int32),
         delta=state.w,
     )
+    return (res, counts) if counters else res
 
 
 # ---------------------------------------------------------------------------

@@ -99,6 +99,14 @@ def main() -> None:
               f"live={rep.live_edges} "
               f"ws/fb={rep.n_workset_ticks}/{rep.n_fallback_ticks} "
               f"pred/miss={rep.n_predicted_ticks}/{rep.n_bucket_miss_ticks}")
+        if rep.round_vertices is not None and rep.round_vertices.size:
+            # the fused engine's counters: rounds that started with an
+            # empty restricted set, and live edges over the slots read
+            dead = int((rep.round_vertices == 0).sum())
+            live = rep.round_edges.sum() / (rep.round_edges.size
+                                            * rep.edge_slots)
+            print(f"rounds={rep.round_vertices.size} dead_rounds={dead} "
+                  f"live_slot_share={100 * live:.2f}%")
 
 
 if __name__ == "__main__":

@@ -25,7 +25,20 @@ The device serving loop here is the production tick pipeline:
   — the transaction timestamp — for aux-using ones),
 * maintenance through the fused, workset, or predictive-workset engine,
   single-device or mesh-sharded,
-* per-tick statistics accumulated on device and drained at shutdown.
+* per-tick statistics accumulated on device and drained at shutdown; the
+  single-device fused engine also returns its per-round counters
+  (:func:`repro.core.incremental.tick_counters`), fetched once after the
+  loop.
+
+Every step of the loop runs under a ``jax.profiler.TraceAnnotation``
+span, so a profiler trace puts the host's steps on the device trace's
+clock: ``spade.seed``, ``spade.upload`` and ``spade.initial_peel`` in
+set-up; per tick ``spade.tick`` (carrying ``tick=<n>``) around
+``spade.read`` (the stream slices), ``spade.prep`` (padding and
+host-to-device transfers), ``spade.weigh`` (``batch_weights`` and the
+benign count), ``spade.dispatch`` (the tick program's call) and
+``spade.refresh``; ``spade.drain`` after the loop.  With no profiler
+attached a span costs about a microsecond.
 
 With ``workset=True, predictive=True`` (the default) the workset buckets
 come from the previous tick's suffix counts and the fit check runs on
@@ -46,6 +59,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.incremental import (
     BucketPredictor,
     DeviceSpadeState,
@@ -58,6 +73,7 @@ from repro.core.incremental import (
     slide_and_maintain,
     slide_and_maintain_auto,
     slide_and_maintain_predictive,
+    tick_counters,
 )
 from repro.core.metrics import DensityMetric
 from repro.core.semantics import SuspSemantics, resolve
@@ -158,6 +174,19 @@ class DeviceServiceReport:
     # predictive-selector telemetry (zeros when predictive=False)
     n_predicted_ticks: int = 0  # ticks dispatched without a count sync
     n_bucket_miss_ticks: int = 0  # predicted buckets the suffix outgrew
+    # the single-device fused engine's counters, one row per tick (None
+    # for the workset, predictive and mesh engines and for an unbounded
+    # peel): the restricted set's active vertices and live edges at the
+    # start of each round, [n_ticks, max_rounds]; a round starting with
+    # no active vertex peels nothing.  Per tick the suffix start r0 and
+    # the suffix's vertices and induced live edges (round 0), [n_ticks];
+    # ``edge_slots`` is the edge buffer's capacity every round streams.
+    round_vertices: np.ndarray | None = None
+    round_edges: np.ndarray | None = None
+    suffix_r0: np.ndarray | None = None
+    suffix_vertices: np.ndarray | None = None
+    suffix_edges: np.ndarray | None = None
+    edge_slots: int | None = None
 
 
 class SpadeService:
@@ -247,21 +276,28 @@ def _run_device_service(
 
     # the semantics' batch-seeding rule: dyadic-snapped edge weights +
     # vertex priors + the degree state the streaming ticks continue from
-    base_aux = np.zeros(m_base) if sem.uses_aux else None
-    base_w, in_deg = sem.seed_base(
-        stream.base_src, stream.base_dst, stream.base_amt, n, aux=base_aux
-    )
-    a0 = sem.seed_vertices(n, in_deg, aux=None)
+    with TraceAnnotation("spade.seed"):
+        base_aux = np.zeros(m_base) if sem.uses_aux else None
+        base_w, in_deg = sem.seed_base(
+            stream.base_src, stream.base_dst, stream.base_amt, n,
+            aux=base_aux,
+        )
+        a0 = sem.seed_vertices(n, in_deg, aux=None)
 
-    g = device_graph_from_coo(
-        n, stream.base_src, stream.base_dst, base_w, a=a0,
-        n_capacity=-(-n // 512) * 512, e_capacity=-(-e_cap // 512) * 512,
-    )
+    with TraceAnnotation("spade.upload"):
+        g = device_graph_from_coo(
+            n, stream.base_src, stream.base_dst, base_w, a=a0,
+            n_capacity=-(-n // 512) * 512, e_capacity=-(-e_cap // 512) * 512,
+        )
+        if mesh is not None:
+            g = shard_graph(g, mesh, axis=shard_axis)
     predictive = spec.workset and spec.predictive
     predictor = None
+    # the single-device fused engine counts its rounds (a bounded peel)
+    counted = mesh is None and not spec.workset and max_rounds > 0
     if mesh is not None:
-        g = shard_graph(g, mesh, axis=shard_axis)
-        state = init_sharded_state(g, mesh, axis=shard_axis, eps=eps)
+        with TraceAnnotation("spade.initial_peel"):
+            state = init_sharded_state(g, mesh, axis=shard_axis, eps=eps)
         refresh = partial(sharded_full_refresh, mesh=mesh, axis=shard_axis)
         if predictive:
             predictor = BucketPredictor(
@@ -283,7 +319,8 @@ def _run_device_service(
             slide = partial(sharded_slide_and_maintain, mesh=mesh,
                             axis=shard_axis)
     else:
-        state = init_state(g, eps=eps)
+        with TraceAnnotation("spade.initial_peel"):
+            state = init_state(g, eps=eps)
         refresh = full_refresh
         if predictive:
             predictor = BucketPredictor(g.n_capacity, g.e_capacity,
@@ -300,9 +337,10 @@ def _run_device_service(
         else:
             maintain = insert_and_maintain
             slide = slide_and_maintain
-    deg_dev = jnp.asarray(in_deg, jnp.int32)
-    if deg_dev.shape[0] < g.n_capacity:
-        deg_dev = jnp.pad(deg_dev, (0, g.n_capacity - deg_dev.shape[0]))
+    with TraceAnnotation("spade.upload"):
+        deg_dev = jnp.asarray(in_deg, jnp.int32)
+        if deg_dev.shape[0] < g.n_capacity:
+            deg_dev = jnp.pad(deg_dev, (0, g.n_capacity - deg_dev.shape[0]))
 
     # the semantics' streamed-tick rule, compiled once for the whole run
     weight_fn = jax.jit(sem.batch_weights)
@@ -321,70 +359,103 @@ def _run_device_service(
     ring: list[int] = []  # per-tick resident edge counts, oldest first
     benign_acc = jnp.int32(0)  # device accumulator, drained at shutdown
     ever_detected = jnp.zeros(g.n_capacity, bool)  # vertices ever in S^P
+    counts: list[jax.Array] = []  # per-tick counter vectors, on device
+    tick_kw = {"counters": True} if counted else {}
     slot_ids = jnp.arange(g.e_capacity, dtype=jnp.int32)
     for i in range(0, n_inc, batch_edges):
-        j = min(i + batch_edges, n_inc)
-        pad = batch_edges - (j - i)
-        bs = np.concatenate([stream.inc_src[i:j], np.zeros(pad, np.int64)])
-        bd = np.concatenate([stream.inc_dst[i:j], np.zeros(pad, np.int64)])
-        amt = np.concatenate([stream.inc_amt[i:j], np.zeros(pad)])
-        valid = np.concatenate([np.ones(j - i, bool), np.zeros(pad, bool)])
-        bs_d = jnp.asarray(bs, jnp.int32)
-        bd_d = jnp.asarray(bd, jnp.int32)
-        valid_d = jnp.asarray(valid)
-        aux_d = None
-        if sem.uses_aux:
-            aux = np.concatenate([stream.inc_time[i:j], np.zeros(pad)])
-            aux_d = jnp.asarray(aux, jnp.float32)
-        w, deg_dev = weight_fn(
-            deg_dev, bs_d, bd_d, jnp.asarray(amt, jnp.float32), valid_d, aux_d
-        )
-        benign_acc = _accum_benign(benign_acc, state, bs_d, bd_d, w, valid_d)
-        t0 = time.perf_counter()
-        info = None
-        if window_ticks and len(ring) >= window_ticks:
-            # fused tick: expire the batch sliding out + insert the new one
-            # with a single warm re-peel.  After compaction the oldest
-            # resident batch always sits right after the base graph.
-            cnt0 = ring.pop(0)
-            drop = (slot_ids >= m_base) & (slot_ids < m_base + cnt0)
-            kw = {"n_dropped": cnt0} if predictive else {}
-            out = slide(
-                state, drop, bs_d, bd_d, w.astype(jnp.float32), valid_d,
-                eps=eps, max_rounds=max_rounds, **kw,
-            )
-            state, info = out if spec.workset else (out, None)
-            n_expired += cnt0
-        else:
-            out = maintain(
-                state, bs_d, bd_d, w.astype(jnp.float32), valid_d,
-                eps=eps, max_rounds=max_rounds,
-            )
-            state, info = out if spec.workset else (out, None)
-        jax.block_until_ready(state.best_g)
-        t_total += time.perf_counter() - t0
-        if info is not None:
-            n_fallback += info.fallback
-            n_workset += not info.fallback
-            n_predicted += info.predicted
-            n_miss += info.miss
-            max_suffix_edges = max(max_suffix_edges, info.n_suffix_edges)
-            max_e_bucket = max(max_e_bucket, info.e_bucket)
-        if window_ticks:
-            ring.append(int(valid.sum()))
-            # a windowed community is transient by design (the evidence
-            # expires); recall is therefore "ever detected while resident",
-            # tracked as a device bool vector and drained once at shutdown
-            ever_detected = _accum_detected(ever_detected, state.community)
-        n_ticks += 1
-        if spec.refresh_every and n_ticks % spec.refresh_every == 0:
-            state = refresh(state, eps=eps)
-            n_refresh += 1
+        with TraceAnnotation("spade.tick", tick=n_ticks):
+            j = min(i + batch_edges, n_inc)
+            pad = batch_edges - (j - i)
+            with TraceAnnotation("spade.read"):
+                src_i = stream.inc_src[i:j]
+                dst_i = stream.inc_dst[i:j]
+                amt_i = stream.inc_amt[i:j]
+                time_i = stream.inc_time[i:j] if sem.uses_aux else None
+            with TraceAnnotation("spade.prep"):
+                bs = np.concatenate([src_i, np.zeros(pad, np.int64)])
+                bd = np.concatenate([dst_i, np.zeros(pad, np.int64)])
+                amt = np.concatenate([amt_i, np.zeros(pad)])
+                valid = np.concatenate([np.ones(j - i, bool),
+                                        np.zeros(pad, bool)])
+                bs_d = jnp.asarray(bs, jnp.int32)
+                bd_d = jnp.asarray(bd, jnp.int32)
+                valid_d = jnp.asarray(valid)
+                amt_d = jnp.asarray(amt, jnp.float32)
+                aux_d = None
+                if sem.uses_aux:
+                    aux = np.concatenate([time_i, np.zeros(pad)])
+                    aux_d = jnp.asarray(aux, jnp.float32)
+            with TraceAnnotation("spade.weigh"):
+                w, deg_dev = weight_fn(deg_dev, bs_d, bd_d, amt_d, valid_d,
+                                       aux_d)
+                benign_acc = _accum_benign(benign_acc, state, bs_d, bd_d, w,
+                                           valid_d)
+            t0 = time.perf_counter()
+            info = None
+            with TraceAnnotation("spade.dispatch"):
+                if window_ticks and len(ring) >= window_ticks:
+                    # fused tick: expire the batch sliding out + insert the
+                    # new one with a single warm re-peel.  After compaction
+                    # the oldest resident batch always sits right after the
+                    # base graph.
+                    cnt0 = ring.pop(0)
+                    drop = (slot_ids >= m_base) & (slot_ids < m_base + cnt0)
+                    kw = {"n_dropped": cnt0} if predictive else {}
+                    out = slide(
+                        state, drop, bs_d, bd_d, w.astype(jnp.float32),
+                        valid_d, eps=eps, max_rounds=max_rounds, **kw,
+                        **tick_kw,
+                    )
+                    n_expired += cnt0
+                else:
+                    out = maintain(
+                        state, bs_d, bd_d, w.astype(jnp.float32), valid_d,
+                        eps=eps, max_rounds=max_rounds, **tick_kw,
+                    )
+            if spec.workset:
+                state, info = out
+            elif isinstance(out, tuple):  # the counted tick: (state, counts)
+                state, cnt = out
+                counts.append(cnt)
+            else:
+                state = out
+            jax.block_until_ready(state.best_g)
+            t_total += time.perf_counter() - t0
+            if info is not None:
+                n_fallback += info.fallback
+                n_workset += not info.fallback
+                n_predicted += info.predicted
+                n_miss += info.miss
+                max_suffix_edges = max(max_suffix_edges, info.n_suffix_edges)
+                max_e_bucket = max(max_e_bucket, info.e_bucket)
+            if window_ticks:
+                ring.append(int(valid.sum()))
+                # a windowed community is transient by design (the
+                # evidence expires); recall is therefore "ever detected
+                # while resident", tracked as a device bool vector and
+                # drained once at shutdown
+                ever_detected = _accum_detected(ever_detected,
+                                                state.community)
+            n_ticks += 1
+            if spec.refresh_every and n_ticks % spec.refresh_every == 0:
+                with TraceAnnotation("spade.refresh"):
+                    state = refresh(state, eps=eps)
+                n_refresh += 1
 
     # drain the device-resident stats once, after the loop
-    benign_total = int(benign_acc)
-    detected = np.where(np.asarray(ever_detected))[0].tolist()
-    comm = set(np.where(np.asarray(state.community))[0].tolist()) | set(detected)
+    with TraceAnnotation("spade.drain"):
+        benign_total = int(benign_acc)
+        detected = np.where(np.asarray(ever_detected))[0].tolist()
+        comm = set(np.where(np.asarray(state.community))[0].tolist()) \
+            | set(detected)
+        final_g = float(state.best_g)
+        live_edges = int(state.edge_count)
+        counted_fields = {}
+        if counted and len(counts) == n_ticks:  # a count for every tick
+            rows = np.stack(jax.device_get(counts)) if counts \
+                else np.zeros((0, 1 + 2 * max_rounds), np.int64)
+            counted_fields = dict(tick_counters(rows, max_rounds),
+                                  edge_slots=int(g.e_capacity))
     fraud = set(stream.fraud_block.tolist())
     recall = len(fraud & comm) / len(fraud) if fraud else 1.0
     return DeviceServiceReport(
@@ -394,15 +465,16 @@ def _run_device_service(
         mean_us_per_edge=1e6 * t_total / max(n_inc, 1),
         benign_fraction=benign_total / max(n_inc, 1),
         fraud_recall=recall,
-        final_g=float(state.best_g),
+        final_g=final_g,
         n_refreshes=n_refresh,
         window_ticks=window_ticks,
         n_expired_edges=n_expired,
-        live_edges=int(state.edge_count),
+        live_edges=live_edges,
         n_workset_ticks=n_workset,
         n_fallback_ticks=n_fallback,
         max_suffix_edges=max_suffix_edges,
         max_e_bucket=max_e_bucket,
         n_predicted_ticks=n_predicted,
         n_bucket_miss_ticks=n_miss,
+        **counted_fields,
     )

@@ -124,6 +124,18 @@ def test_insert_and_maintain_compiles_at_grab4_width(one_chip):
     assert _per_device_bytes(compiled) < HBM_BYTES
 
 
+def test_counted_tick_compiles_at_grab4_width(one_chip):
+    """The served loop's tick: the same program with its round counters,
+    and the rounds' ops under their named scopes in the chip's HLO."""
+    compiled = insert_and_maintain.lower(
+        _state(one_chip, one_chip), *_batch(one_chip),
+        eps=CONFIG.eps, max_rounds=CONFIG.max_rounds, counters=True,
+    ).compile()
+    text = compiled.as_text()
+    assert "/peel_gather/" in text and "/peel_scatter/" in text
+    assert _per_device_bytes(compiled) < HBM_BYTES
+
+
 def test_sharded_insert_and_maintain_compiles_on_v5e_2x2(mesh4):
     edges, repl = NamedSharding(mesh4, P("data")), NamedSharding(mesh4, P())
     compiled = sharded_insert_and_maintain.lower(

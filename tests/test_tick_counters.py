@@ -1,0 +1,230 @@
+"""The fused tick's round counters, its named scopes, and the served
+loop's ``spade.*`` profiler spans.
+
+Counters are checked against a brute-force numpy recount: the same
+bounded warm re-peel, replayed round by round on the host from the
+pre-tick levels and the post-append edge buffer, on integer weights (so
+every f32 sum is exact and the two peels take the same rounds).
+"""
+
+from __future__ import annotations
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.core.incremental import (
+    DeviceSpadeState,
+    init_state,
+    insert_and_maintain,
+    slide_and_maintain,
+    tick_counters,
+)
+from repro.graphstore.generators import make_transaction_stream
+from repro.graphstore.structs import device_graph_from_coo
+from repro.serve import EngineSpec, SpadeService
+
+EPS = 0.1
+
+
+def _graph(rng, n=120, m=900, e_cap=1536):
+    src = rng.integers(0, n, m)
+    dst = rng.integers(0, n, m)
+    keep = src != dst
+    src, dst = src[keep], dst[keep]
+    # a planted dense block keeps the suffix alive for a few rounds
+    blk = rng.integers(0, 12, (200, 2))
+    blk = blk[blk[:, 0] != blk[:, 1]]
+    src = np.concatenate([src, blk[:, 0]])
+    dst = np.concatenate([dst, blk[:, 1]])
+    c = rng.integers(1, 4, src.shape[0]).astype(np.float32)
+    a = rng.integers(0, 3, n).astype(np.float32)
+    return device_graph_from_coo(n, src, dst, c, a=a, e_capacity=e_cap)
+
+
+def _batch(rng, n, b=64, n_valid=50):
+    src = rng.integers(0, n, b).astype(np.int32)
+    dst = ((src + 1 + rng.integers(0, n - 1, b)) % n).astype(np.int32)
+    c = rng.integers(1, 4, b).astype(np.float32)
+    valid = np.arange(b) < n_valid
+    return src, dst, c, valid
+
+
+def _numpy_recount(level0, g, src_b, dst_b, valid, drop_endpoints,
+                   max_rounds):
+    """r0 and the per-round (active vertices, live edges) of the warm
+    re-peel, recomputed on the host from the post-append graph ``g``."""
+    e_src, e_dst = np.asarray(g.src), np.asarray(g.dst)
+    c, emask = np.asarray(g.c), np.asarray(g.edge_mask)
+    a, vmask = np.asarray(g.a), np.asarray(g.vertex_mask)
+    ends = np.concatenate([src_b[valid], dst_b[valid], drop_endpoints])
+    r0 = min(int(level0[ends].min()), 2**30)
+    active = (level0 >= r0) & vmask
+    alive = active[e_src] & active[e_dst] & emask
+    cm = np.where(alive, c, 0.0)
+    V = a.shape[0]
+    w = np.where(active, a, 0.0) + np.bincount(e_src, cm, V) \
+        + np.bincount(e_dst, cm, V)
+    f = np.float32(np.where(active, a, 0.0).sum() + cm.sum())
+    n_act = int(active.sum())
+    rv, re = [], []
+    for _ in range(max_rounds):
+        rv.append(n_act)
+        re.append(int(alive.sum()))
+        g_cur = np.float32(f) / np.float32(max(n_act, 1))
+        peel = active & (w <= np.float32(2.0 * (1.0 + EPS)) * g_cur)
+        if not peel.any() and active.any():
+            peel = active & (w <= w[active].min())
+        e_ps, e_pd = peel[e_src], peel[e_dst]
+        cm = np.where(alive, c, 0.0)
+        w = w - np.bincount(e_dst, np.where(e_ps & ~e_pd, cm, 0.0), V) \
+            - np.bincount(e_src, np.where(e_pd & ~e_ps, cm, 0.0), V)
+        alive = alive & ~(e_ps | e_pd)
+        active = active & ~peel
+        n_act = int(active.sum())
+        f = np.float32(np.where(active, a, 0.0).sum()
+                       + np.where(alive, c, 0.0).sum())
+    return r0, rv, re
+
+
+@pytest.mark.parametrize("kind", ["insert", "slide"])
+@pytest.mark.parametrize("max_rounds", [3, 40])
+def test_counters_match_numpy_recount(kind, max_rounds):
+    rng = np.random.default_rng(11 + max_rounds)
+    state = init_state(_graph(rng), eps=EPS)
+    n = int(np.asarray(state.graph.vertex_mask).sum())
+    for tick in range(3):
+        src_b, dst_b, c_b, valid = _batch(rng, n)
+        level0 = np.asarray(state.level).copy()
+        args = (jnp.asarray(src_b), jnp.asarray(dst_b), jnp.asarray(c_b),
+                jnp.asarray(valid))
+        drop_ends = np.zeros(0, np.int64)
+        if kind == "slide":
+            # expire the oldest 40 live slots (the window's head)
+            drop = np.zeros(state.graph.e_capacity, bool)
+            drop[:40] = True
+            drop &= np.asarray(state.graph.edge_mask)
+            drop_ends = np.concatenate([np.asarray(state.graph.src)[drop],
+                                        np.asarray(state.graph.dst)[drop]])
+            state, cnt = slide_and_maintain(
+                state, jnp.asarray(drop), *args, eps=EPS,
+                max_rounds=max_rounds, counters=True)
+        else:
+            state, cnt = insert_and_maintain(
+                state, *args, eps=EPS, max_rounds=max_rounds, counters=True)
+        cnt = np.asarray(cnt)
+        assert cnt.dtype == np.int32 and cnt.shape == (1 + 2 * max_rounds,)
+        r0, rv, re = _numpy_recount(level0, state.graph, src_b, dst_b,
+                                    valid, drop_ends, max_rounds)
+        got = tick_counters(cnt[None], max_rounds)
+        assert int(got["suffix_r0"][0]) == r0
+        assert got["round_vertices"][0].tolist() == rv
+        assert got["round_edges"][0].tolist() == re
+        assert int(got["suffix_vertices"][0]) == rv[0]
+        assert int(got["suffix_edges"][0]) == re[0]
+    if max_rounds == 40:
+        # the suffix drains well before 40 rounds: the rest read 0
+        assert rv[-1] == 0 and re[-1] == 0
+    else:
+        assert rv[-1] > 0
+
+
+def test_counters_off_returns_the_state_alone():
+    """Off, the tick returns the state alone; on, the same state."""
+    states = []
+    for counters in (False, True):
+        rng = np.random.default_rng(3)
+        state = init_state(_graph(rng), eps=EPS)
+        src_b, dst_b, c_b, valid = _batch(rng, 120)
+        out = insert_and_maintain(
+            state, jnp.asarray(src_b), jnp.asarray(dst_b), jnp.asarray(c_b),
+            jnp.asarray(valid), eps=EPS, max_rounds=4, counters=counters)
+        states.append(out[0] if counters else out)
+    assert isinstance(states[0], DeviceSpadeState)
+    for field in ("level", "best_g", "community", "w0", "edge_count"):
+        np.testing.assert_array_equal(getattr(states[0], field),
+                                      getattr(states[1], field))
+
+
+def test_counters_need_a_bounded_peel():
+    rng = np.random.default_rng(4)
+    state = init_state(_graph(rng), eps=EPS)
+    src_b, dst_b, c_b, valid = _batch(rng, 120)
+    with pytest.raises(ValueError, match="bounded"):
+        insert_and_maintain(state, jnp.asarray(src_b), jnp.asarray(dst_b),
+                            jnp.asarray(c_b), jnp.asarray(valid), eps=EPS,
+                            max_rounds=0, counters=True)
+
+
+def test_compiled_tick_carries_the_named_scopes():
+    """The scopes reach the compiled program's ``op_name`` metadata, which
+    the device trace's ops carry; the counters do not rename the module."""
+    rng = np.random.default_rng(5)
+    state = init_state(_graph(rng), eps=EPS)
+    src_b, dst_b, c_b, valid = _batch(rng, 120)
+    args = (state, jnp.asarray(src_b), jnp.asarray(dst_b), jnp.asarray(c_b),
+            jnp.asarray(valid))
+    for counters in (False, True):
+        lowered = insert_and_maintain.lower(*args, eps=EPS, max_rounds=4,
+                                            counters=counters)
+        hlo = lowered.compile().as_text()
+        for scope in ("peel_gather", "peel_scatter", "peel_update",
+                      "tick_prologue", "tick_append", "tick_seed",
+                      "tick_rounds", "tick_merge"):
+            assert f"/{scope}/" in hlo, scope
+        assert "jit_insert_and_maintain" in lowered.as_text()
+
+
+def _host_spans(path):
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(str(path))
+    out = []
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("spade."):
+                    out.append((e.name, e.start_ns, e.start_ns
+                                + e.duration_ns, dict(e.stats)))
+    return out
+
+
+def test_served_loop_spans_in_a_profiler_trace(tmp_path):
+    stream = make_transaction_stream(n=600, m=3000, seed=2)
+    spec = EngineSpec(batch_edges=128, max_rounds=6)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        rep = SpadeService("DG", spec).run(stream)
+    finally:
+        jax.profiler.stop_trace()
+    files = sorted(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    spans = _host_spans(files[-1])
+    ticks = sorted((s for s in spans if s[0] == "spade.tick"),
+                   key=lambda s: s[1])
+    assert rep.n_ticks > 1
+    assert len(ticks) == rep.n_ticks
+    assert [int(s[3]["tick"]) for s in ticks] == list(range(rep.n_ticks))
+    dispatches = [s for s in spans if s[0] == "spade.dispatch"]
+    for _, t0, t1, _ in ticks:
+        inside = [d for d in dispatches if t0 <= d[1] and d[2] <= t1]
+        assert len(inside) == 1
+    names = {s[0] for s in spans}
+    assert {"spade.seed", "spade.upload", "spade.initial_peel", "spade.read",
+            "spade.prep", "spade.weigh", "spade.drain"} <= names
+    # the fused engine's counters ride in the same report
+    assert rep.round_vertices.shape == (rep.n_ticks, 6)
+    assert rep.edge_slots > 0
+
+
+def test_workset_engine_leaves_the_counters_empty():
+    stream = make_transaction_stream(n=600, m=3000, seed=2)
+    rep = SpadeService("DG", EngineSpec(batch_edges=128, max_rounds=6,
+                                        workset=True)).run(stream)
+    assert rep.round_vertices is None and rep.edge_slots is None
