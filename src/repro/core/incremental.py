@@ -230,41 +230,42 @@ def _slide_epilogue(
 
 
 def tick_counters(counts: np.ndarray, max_rounds: int) -> dict:
-    """Split the fused tick's counter vectors, stacked ``[n_ticks, 1 + 2 *
+    """Split the fused tick's counter vectors, stacked ``[n_ticks, 1 + 3 *
     max_rounds]`` on the host, into named ``int64`` arrays.
 
     ``suffix_r0``: the tick's affected-suffix start ``r0``;
     ``round_vertices[t, i]`` / ``round_edges[t, i]``: active vertices and
-    live edges of the restricted set at the start of round ``i``.  Round 0
-    holds the whole suffix: ``suffix_vertices`` and ``suffix_edges`` (the
-    suffix's induced live edges) are its column.
+    live edges of the restricted set at the start of round ``i``;
+    ``round_slots[t, i]``: the edge slots round ``i`` streamed (0 where no
+    round ran, :func:`repro.core.peel._run_stages`).  Round 0 holds the
+    whole suffix: ``suffix_vertices`` and ``suffix_edges`` (the suffix's
+    induced live edges) are its column.
     """
-    counts = np.asarray(counts, np.int64).reshape(-1, 1 + 2 * max_rounds)
-    rv = counts[:, 1:1 + max_rounds]
-    re = counts[:, 1 + max_rounds:]
+    counts = np.asarray(counts, np.int64).reshape(-1, 1 + 3 * max_rounds)
+    rv, re, rs = np.split(counts[:, 1:], 3, axis=1)
     return {"suffix_r0": counts[:, 0], "suffix_vertices": rv[:, 0].copy(),
             "suffix_edges": re[:, 0].copy(), "round_vertices": rv,
-            "round_edges": re}
+            "round_edges": re, "round_slots": rs}
 
 
 def _warm_and_merge(state, g, bk, n_removed, src, dst, c, valid, eps,
-                    max_rounds, unroll, counters, with_drops=True):
+                    max_rounds, counters, with_drops=True):
     """The fused tick's warm re-peel and merge; with ``counters`` also its
-    int32 counter vector ``[r0, round_vertices..., round_edges...]``."""
+    int32 counter vector ``[r0, round_vertices..., round_edges...,
+    round_slots...]``."""
     res = bulk_peel_warm(g, bk.keep, prior_best_g=bk.prior_g, eps=eps,
-                         max_rounds=max_rounds, unroll=unroll,
-                         counters=counters)
+                         max_rounds=max_rounds, counters=counters)
     if counters:
-        res, (rv, re) = res
+        res, rounds = res
     with jax.named_scope("tick_merge"):
         new = _slide_epilogue(state, g, res, bk, n_removed, src, dst, c,
                               valid, with_drops=with_drops)
     if not counters:
         return new
-    return new, jnp.concatenate([bk.r0.astype(jnp.int32)[None], rv, re])
+    return new, jnp.concatenate([bk.r0.astype(jnp.int32)[None], *rounds])
 
 
-@partial(jax.jit, static_argnames=("eps", "max_rounds", "unroll", "counters"),
+@partial(jax.jit, static_argnames=("eps", "max_rounds", "counters"),
          donate_argnames=("state",))
 def insert_and_maintain(
     state: DeviceSpadeState,
@@ -274,7 +275,6 @@ def insert_and_maintain(
     valid: jax.Array,
     eps: float = 0.1,
     max_rounds: int = 0,
-    unroll: bool = False,
     counters: bool = False,
 ):
     """Insert an edge batch and maintain the community incrementally.
@@ -300,8 +300,7 @@ def insert_and_maintain(
         g = append_edges(state.graph, state.edge_count, src, dst, c,
                          valid=valid)
     return _warm_and_merge(state, g, bk, jnp.int32(0), src, dst, c, valid,
-                           eps, max_rounds, unroll, counters,
-                           with_drops=False)
+                           eps, max_rounds, counters, with_drops=False)
 
 
 def delete_and_maintain(
@@ -309,7 +308,6 @@ def delete_and_maintain(
     drop: jax.Array,
     eps: float = 0.1,
     max_rounds: int = 0,
-    unroll: bool = False,
 ) -> DeviceSpadeState:
     """Delete the edges in slot mask ``drop`` and maintain incrementally.
 
@@ -332,11 +330,11 @@ def delete_and_maintain(
     z = jnp.zeros(1, jnp.int32)
     return slide_and_maintain(
         state, drop, z, z, z.astype(jnp.float32), jnp.zeros(1, bool),
-        eps=eps, max_rounds=max_rounds, unroll=unroll,
+        eps=eps, max_rounds=max_rounds,
     )
 
 
-@partial(jax.jit, static_argnames=("eps", "max_rounds", "unroll", "counters"),
+@partial(jax.jit, static_argnames=("eps", "max_rounds", "counters"),
          donate_argnames=("state",))
 def slide_and_maintain(
     state: DeviceSpadeState,
@@ -347,7 +345,6 @@ def slide_and_maintain(
     valid: jax.Array,
     eps: float = 0.1,
     max_rounds: int = 0,
-    unroll: bool = False,
     counters: bool = False,
 ):
     """One fused sliding-window tick: expire ``drop``, insert the batch,
@@ -371,7 +368,7 @@ def slide_and_maintain(
         g = append_edges(g, state.edge_count - n_removed, src, dst, c,
                          valid=valid)
     return _warm_and_merge(state, g, bk, n_removed, src, dst, c, valid,
-                           eps, max_rounds, unroll, counters)
+                           eps, max_rounds, counters)
 
 
 # ---------------------------------------------------------------------------

@@ -43,6 +43,7 @@ __all__ = [
     "bulk_peel_warm",
     "bulk_peel_warm_workset",
     "bulk_peel_warm_checked",
+    "edge_ladder",
     "select_bucket",
     "workset_sizes",
 ]
@@ -325,26 +326,18 @@ def _round_step(
     )
 
 
-def _bulk_round(
-    g: DeviceGraph, eps: float, s: _BulkState, use_kernel: bool = False
-) -> _BulkState:
-    """One full-buffer bulk-peeling round (see :func:`_round_step`)."""
-    return _round_step(g.src, g.dst, g.c, g.a, eps, use_kernel, s)
-
-
-@partial(jax.jit, static_argnames=("eps", "max_rounds", "unroll", "use_kernel"))
+@partial(jax.jit, static_argnames=("eps", "max_rounds", "use_kernel"))
 def bulk_peel(
     g: DeviceGraph,
     eps: float = 0.1,
     max_rounds: int = 0,
-    unroll: bool = False,
     use_kernel: bool = False,
 ) -> PeelResultDevice:
     """Threshold bulk peeling; guarantees ``g_best >= g* / (2(1+eps))``.
 
-    ``max_rounds = 0`` runs to completion (while_loop); a positive value
-    bounds the round count (useful for fixed-cost serving ticks).
-    ``unroll`` python-unrolls max_rounds rounds (roofline lowering).
+    ``max_rounds = 0`` runs to completion; a positive value bounds the
+    round count (useful for fixed-cost serving ticks).  The rounds stream
+    a buffer that shrinks with the live edges (:func:`_run_stages`).
     ``use_kernel`` routes the per-round elementwise update through the
     fused ``peel_round`` kernel (bit-identical on integer weights).
     """
@@ -361,9 +354,8 @@ def bulk_peel(
         round_=jnp.int32(0),
     )
 
-    state = _run_rounds(
-        partial(_bulk_round, g, eps, use_kernel=use_kernel), init, max_rounds, unroll
-    )
+    state, _ = _run_stages(g.src, g.dst, g.c, g.a, eps, use_kernel, init,
+                           max_rounds)
     return PeelResultDevice(
         level=state.level,
         best_level=state.best_level,
@@ -385,25 +377,114 @@ def _run_rounds(round_fn, init, max_rounds: int, unroll: bool = False):
     return jax.lax.while_loop(lambda s: s.n_act > 0, round_fn, init)
 
 
-def _run_rounds_counted(round_fn, init, max_rounds: int, unroll: bool = False):
-    """:func:`_run_rounds` for a bounded peel that also counts, at the start
-    of every round, the restricted set's active vertices and live edges.
+# The staged rounds.  A full-buffer round streams every edge slot, however
+# few edges the restricted set still holds; on Grab4 the live edges fall
+# about fourfold a round.  So the rounds step down a static ladder of
+# buffer sizes, compacting the live edges into the next size once they fit.
 
-    Returns ``(state, (round_vertices, round_edges))``, two int32
-    ``[max_rounds]`` vectors.  A round that starts with no active vertex
-    peels nothing: its entry reads 0.  The edge count adds one [E] bool
-    reduction per round.
+_LADDER_FLOOR = 65536  # edge slots of the smallest stage
+
+
+def edge_ladder(e_capacity: int) -> tuple[int, ...]:
+    """The edge-buffer sizes the staged rounds step down through.
+
+    Derived from the capacity alone, so every stage has a static shape:
+    the capacity, then about a quarter of the size above, rounded up to a
+    multiple of 512, down to a floor of 64K slots.  A buffer below four
+    times the floor keeps one stage.
     """
-    if max_rounds <= 0:
+    sizes = [int(e_capacity)]
+    if e_capacity >= 4 * _LADDER_FLOOR:
+        while sizes[-1] > _LADDER_FLOOR:
+            quarter = -(-sizes[-1] // 4)
+            sizes.append(max(-(-quarter // 512) * 512, _LADDER_FLOOR))
+    return tuple(sizes)
+
+
+def _compact_edges(src, dst, c, alive, size):
+    """The live edges, in slot order, in a ``size``-slot buffer.
+
+    The caller guarantees they fit.  One sort carries the edge arrays on
+    a key that is a live edge's slot and the capacity for a dead one, so
+    the live edges come first, in slot order.  (On a TPU v5e it beat a
+    unique-index scatter and a gather after a key-only sort fourfold at
+    32.5M -> 8.13M slots, PERF.md §3.)  Pad lanes get endpoint 0, ``c =
+    0`` and ``alive = False``, as :class:`Workset` pads do.
+    """
+    E = alive.shape[0]
+    key = jnp.where(alive, jnp.arange(E, dtype=jnp.int32), E)
+    _, src, dst, c = jax.lax.sort((key, src, dst, c), num_keys=1)
+    alive = jnp.arange(size, dtype=jnp.int32) < jnp.sum(alive,
+                                                         dtype=jnp.int32)
+    return (jnp.where(alive, src[:size], 0), jnp.where(alive, dst[:size], 0),
+            jnp.where(alive, c[:size], 0.0), alive)
+
+
+def _run_stages(src, dst, c, a, eps, use_kernel, init, max_rounds,
+                counters=False):
+    """Bulk rounds over an edge buffer that shrinks with the restricted set.
+
+    Stage k runs :func:`_round_step` on a buffer of ``edge_ladder(E)[k]``
+    slots while the set has an active vertex, a bounded peel has a round
+    left, and the live edges exceed the next size down; then it compacts
+    the live edges into the next stage's buffer (scope ``peel_compact``).
+    Once the set is empty, or the rounds are spent, no further round or
+    compaction runs.  The ``[V]`` arrays stay full width and unrelabelled,
+    so each round computes what a full-buffer round would: on integer
+    weights every sum is the same integer in any order, and the result is
+    bit-identical.  A round on an empty set changes nothing but the round
+    index, so a bounded peel still reports ``max_rounds`` rounds.
+
+    Returns ``(state, counts)``.  With ``counters`` (a bounded peel only)
+    ``counts`` is ``(round_vertices, round_edges, round_slots)``, int32
+    ``[max_rounds]``: the set's active vertices and live edges at the
+    start of each round, and the slots the round streamed; 0 where no
+    round ran.  Otherwise ``counts`` is ``None``.
+    """
+    if counters and max_rounds <= 0:
         raise ValueError("round counters need a bounded peel (max_rounds > 0)")
+    ladder = edge_ladder(src.shape[0])
 
-    def step(s, _):
-        counts = (s.n_act.astype(jnp.int32),
-                  jnp.sum(s.edge_alive, dtype=jnp.int32))
-        return round_fn(s), counts
+    def more(s):
+        go = s.n_act > 0
+        return go & (s.round_ < max_rounds) if max_rounds else go
 
-    return jax.lax.scan(step, init, None, length=max_rounds,
-                        unroll=max_rounds if unroll else 1)
+    zeros = jnp.zeros(max_rounds, jnp.int32)
+    carry = (init, jnp.sum(init.edge_alive, dtype=jnp.int32),
+             (zeros, zeros, zeros) if counters else ())
+    for k, size in enumerate(ladder):
+        nxt = ladder[k + 1] if k + 1 < len(ladder) else None
+        edges = (src, dst, c)
+
+        def cond(carry, nxt=nxt):
+            s, n_live, _ = carry
+            return more(s) if nxt is None else more(s) & (n_live > nxt)
+
+        def body(carry, edges=edges, size=size):
+            s, n_live, counts = carry
+            if counters:
+                i = s.round_
+                rv, re, rs = counts
+                counts = (rv.at[i].set(s.n_act.astype(jnp.int32)),
+                          re.at[i].set(n_live), rs.at[i].set(size))
+            s = _round_step(*edges, a, eps, use_kernel, s)
+            return s, jnp.sum(s.edge_alive, dtype=jnp.int32), counts
+
+        s, n_live, counts = jax.lax.while_loop(cond, body, carry)
+        if nxt is not None:
+            with jax.named_scope("peel_compact"):
+                src, dst, c, alive = jax.lax.cond(
+                    more(s),
+                    partial(_compact_edges, src, dst, c, s.edge_alive, nxt),
+                    lambda nxt=nxt: (jnp.zeros(nxt, jnp.int32),
+                                     jnp.zeros(nxt, jnp.int32),
+                                     jnp.zeros(nxt, jnp.float32),
+                                     jnp.zeros(nxt, bool)))
+            s = s._replace(edge_alive=alive)
+        carry = (s, n_live, counts)
+    if max_rounds:
+        s = s._replace(round_=jnp.int32(max_rounds))
+    return s, (counts if counters else None)
 
 
 def bulk_peel_warm(
@@ -412,7 +493,6 @@ def bulk_peel_warm(
     prior_best_g: jax.Array,
     eps: float = 0.1,
     max_rounds: int = 0,
-    unroll: bool = False,
     use_kernel: bool = False,
     counters: bool = False,
 ):
@@ -424,15 +504,16 @@ def bulk_peel_warm(
     and the 2(1+eps) guarantee is preserved (DESIGN.md §2).  ``prior_best_g``
     seeds the best-density tracker so the maintained best never regresses.
 
-    This is the **full-buffer** warm path: every round still streams the
-    capacity-padded ``[E]``/``[V]`` buffers.  The workset twin
+    This is the **full-buffer** warm path: it starts from the
+    capacity-padded ``[E]`` buffer and compacts the live edges down a
+    static size ladder as the set shrinks (:func:`_run_stages`); the
+    ``[V]`` arrays stay full width.  The workset twin
     (:func:`bulk_peel_warm_workset`) gathers the suffix into compact
-    bucketed buffers first and is the steady-state serving path; this
-    function remains the fallback when the suffix exceeds the largest
-    bucket (DESIGN.md §8).
+    bucketed buffers before the first round; this function is the
+    fallback when the suffix exceeds the largest bucket (DESIGN.md §8).
 
     ``counters`` (a bounded peel only) returns ``(result, (round_vertices,
-    round_edges))``: see :func:`_run_rounds_counted`.
+    round_edges, round_slots))``: see :func:`_run_stages`.
     """
     V = g.n_capacity
     with jax.named_scope("tick_seed"):
@@ -455,13 +536,9 @@ def bulk_peel_warm(
         best_level=jnp.int32(0),
         round_=jnp.int32(0),
     )
-    round_fn = partial(_bulk_round, g, eps, use_kernel=use_kernel)
     with jax.named_scope("tick_rounds"):
-        if counters:
-            state, counts = _run_rounds_counted(round_fn, init, max_rounds,
-                                                unroll)
-        else:
-            state = _run_rounds(round_fn, init, max_rounds, unroll)
+        state, counts = _run_stages(g.src, g.dst, g.c, g.a, eps, use_kernel,
+                                    init, max_rounds, counters)
     res = PeelResultDevice(
         level=state.level,
         best_level=state.best_level,

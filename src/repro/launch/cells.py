@@ -497,25 +497,23 @@ _DG_LOGICAL = dict(
 )
 
 
-def _spade_cells(arch, cfg: SpadeConfig, spec: ShapeSpec, concrete, rng,
-                 unroll: bool = False) -> Cell:
+def _spade_cells(arch, cfg: SpadeConfig, spec: ShapeSpec, concrete, rng) -> Cell:
     Ncap, Ecap = _round_up(cfg.n_capacity), _round_up(cfg.e_capacity)
     gl = DeviceGraph(
         n_capacity=Ncap, e_capacity=Ecap, **{k: v for k, v in _DG_LOGICAL.items()}
     )
     # essential per-round work: 2 segment-sum adds + 2 mask mults per edge,
-    # plus threshold compare/update over vertices
+    # plus threshold compare/update over vertices; an upper bound, since
+    # the staged rounds stream fewer slots than the capacity (round_slots)
     E, R = Ecap, cfg.max_rounds
     mf = float(R) * (6.0 * E + 4.0 * Ncap)
     if spec.kind == "spade_static":
-        fn = functools.partial(bulk_peel, eps=cfg.eps, max_rounds=cfg.max_rounds,
-                               unroll=unroll)
+        fn = functools.partial(bulk_peel, eps=cfg.eps, max_rounds=cfg.max_rounds)
         g = _spade_graph(cfg, concrete, rng)
         return Cell(arch, spec.name, "spade", "bulk_peel", fn, (g,),
                     (gl,), None, model_flops=mf)
     # streaming maintenance cell
-    fn = functools.partial(insert_and_maintain, eps=cfg.eps, max_rounds=cfg.max_rounds,
-                           unroll=unroll)
+    fn = functools.partial(insert_and_maintain, eps=cfg.eps, max_rounds=cfg.max_rounds)
     B = cfg.batch_edges
     if concrete:
         g = _spade_graph(cfg, True, rng)
@@ -559,7 +557,8 @@ def build_cell(arch: str, shape: str, *, concrete: bool = False, smoke: bool = F
     (XLA cost_analysis counts while bodies once — DESIGN.md §7), coarse
     attention blocks to bound HLO size, microbatches=1 (identical total
     FLOPs).  Never executed; memory numbers come from the production
-    variant."""
+    variant.  Spade cells have no analysis variant: their staged peel
+    rounds are while loops whatever the flag."""
     fam = ARCH_FAMILY[arch]
     spec = arch_shapes(arch)[shape]
     if isinstance(spec, Skip):
@@ -587,7 +586,7 @@ def build_cell(arch: str, shape: str, *, concrete: bool = False, smoke: bool = F
     if fam == "recsys":
         return _recsys_cell(arch, cfg, spec, concrete, rng)
     if fam == "spade":
-        return _spade_cells(arch, cfg, spec, concrete, rng, unroll=roofline)
+        return _spade_cells(arch, cfg, spec, concrete, rng)
     raise KeyError(arch)
 
 

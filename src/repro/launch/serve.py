@@ -101,12 +101,15 @@ def main() -> None:
               f"pred/miss={rep.n_predicted_ticks}/{rep.n_bucket_miss_ticks}")
         if rep.round_vertices is not None and rep.round_vertices.size:
             # the fused engine's counters: rounds that started with an
-            # empty restricted set, and live edges over the slots read
+            # empty restricted set, and live edges and streamed slots over
+            # the slots full-buffer rounds would read
             dead = int((rep.round_vertices == 0).sum())
-            live = rep.round_edges.sum() / (rep.round_edges.size
-                                            * rep.edge_slots)
+            full = rep.round_edges.size * rep.edge_slots
+            live = rep.round_edges.sum() / full
+            streamed = rep.round_slots.sum() / full
             print(f"rounds={rep.round_vertices.size} dead_rounds={dead} "
-                  f"live_slot_share={100 * live:.2f}%")
+                  f"live_slot_share={100 * live:.2f}% "
+                  f"streamed_slot_share={100 * streamed:.2f}%")
 
 
 if __name__ == "__main__":

@@ -178,11 +178,15 @@ class DeviceServiceReport:
     # for the workset, predictive and mesh engines and for an unbounded
     # peel): the restricted set's active vertices and live edges at the
     # start of each round, [n_ticks, max_rounds]; a round starting with
-    # no active vertex peels nothing.  Per tick the suffix start r0 and
-    # the suffix's vertices and induced live edges (round 0), [n_ticks];
-    # ``edge_slots`` is the edge buffer's capacity every round streams.
+    # no active vertex peels nothing.  ``round_slots``, [n_ticks,
+    # max_rounds]: the edge slots each round streamed, a size of the
+    # rounds' ladder (``core.peel.edge_ladder``), 0 where no round ran.
+    # Per tick the suffix start r0 and the suffix's vertices and induced
+    # live edges (round 0), [n_ticks]; ``edge_slots`` is the edge
+    # buffer's capacity, which the first stage of rounds streams.
     round_vertices: np.ndarray | None = None
     round_edges: np.ndarray | None = None
+    round_slots: np.ndarray | None = None
     suffix_r0: np.ndarray | None = None
     suffix_vertices: np.ndarray | None = None
     suffix_edges: np.ndarray | None = None
@@ -453,7 +457,7 @@ def _run_device_service(
         counted_fields = {}
         if counted and len(counts) == n_ticks:  # a count for every tick
             rows = np.stack(jax.device_get(counts)) if counts \
-                else np.zeros((0, 1 + 2 * max_rounds), np.int64)
+                else np.zeros((0, 1 + 3 * max_rounds), np.int64)
             counted_fields = dict(tick_counters(rows, max_rounds),
                                   edge_slots=int(g.e_capacity))
     fraud = set(stream.fraud_block.tolist())

@@ -137,8 +137,8 @@ def test_peel_round_interpret_vs_ref(V, seed):
 
 
 def test_peel_round_consistent_with_bulk_peel_semantics():
-    """One fused-kernel round == one _bulk_round step (weights/masks)."""
-    from repro.core.peel import _BulkState, _bulk_round
+    """One fused-kernel round == one full-buffer round step (weights/masks)."""
+    from repro.core.peel import _BulkState, _round_step
     from repro.graphstore.structs import device_graph_from_coo
 
     rng = np.random.default_rng(4)
@@ -155,7 +155,7 @@ def test_peel_round_consistent_with_bulk_peel_semantics():
                     n_act=jnp.sum(g.vertex_mask),
                     level=jnp.full(n, -1, jnp.int32), best_g=jnp.float32(-1e30),
                     best_level=jnp.int32(0), round_=jnp.int32(0))
-    nxt = _bulk_round(g, 0.1, st)
+    nxt = _round_step(g.src, g.dst, g.c, g.a, 0.1, False, st)
 
     g_cur = f0 / jnp.maximum(st.n_act, 1)
     thresh = 2.0 * 1.1 * g_cur

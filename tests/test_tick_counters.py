@@ -22,6 +22,7 @@ from repro.core.incremental import (
     slide_and_maintain,
     tick_counters,
 )
+from repro.core.peel import edge_ladder
 from repro.graphstore.generators import make_transaction_stream
 from repro.graphstore.structs import device_graph_from_coo
 from repro.serve import EngineSpec, SpadeService
@@ -89,11 +90,16 @@ def _numpy_recount(level0, g, src_b, dst_b, valid, drop_endpoints,
     return r0, rv, re
 
 
+@pytest.mark.parametrize("width", ["narrow", "wide"])
 @pytest.mark.parametrize("kind", ["insert", "slide"])
 @pytest.mark.parametrize("max_rounds", [3, 40])
-def test_counters_match_numpy_recount(kind, max_rounds):
+def test_counters_match_numpy_recount(kind, max_rounds, width):
     rng = np.random.default_rng(11 + max_rounds)
-    state = init_state(_graph(rng), eps=EPS)
+    # wide: a buffer of three ladder stages, which the rounds step down
+    graph = _graph(rng) if width == "narrow" else _graph(
+        rng, n=6000, m=280_000, e_cap=1 << 19)
+    ladder = edge_ladder(graph.e_capacity)
+    state = init_state(graph, eps=EPS)
     n = int(np.asarray(state.graph.vertex_mask).sum())
     for tick in range(3):
         src_b, dst_b, c_b, valid = _batch(rng, n)
@@ -115,7 +121,7 @@ def test_counters_match_numpy_recount(kind, max_rounds):
             state, cnt = insert_and_maintain(
                 state, *args, eps=EPS, max_rounds=max_rounds, counters=True)
         cnt = np.asarray(cnt)
-        assert cnt.dtype == np.int32 and cnt.shape == (1 + 2 * max_rounds,)
+        assert cnt.dtype == np.int32 and cnt.shape == (1 + 3 * max_rounds,)
         r0, rv, re = _numpy_recount(level0, state.graph, src_b, dst_b,
                                     valid, drop_ends, max_rounds)
         got = tick_counters(cnt[None], max_rounds)
@@ -124,6 +130,16 @@ def test_counters_match_numpy_recount(kind, max_rounds):
         assert got["round_edges"][0].tolist() == re
         assert int(got["suffix_vertices"][0]) == rv[0]
         assert int(got["suffix_edges"][0]) == re[0]
+        # each round streamed a ladder size that holds its live edges; no
+        # slot where no round ran; the sizes only step down
+        rs = got["round_slots"][0]
+        ran = np.asarray(rv) > 0
+        assert set(rs[ran]) <= set(ladder)
+        assert (rs >= np.asarray(re)).all()
+        assert ((rs == 0) == ~ran).all()
+        assert (np.diff(rs) <= 0).all()
+        if width == "wide" and max_rounds == 40:
+            assert len(set(rs[ran])) > 1
     if max_rounds == 40:
         # the suffix drains well before 40 rounds: the rest read 0
         assert rv[-1] == 0 and re[-1] == 0
