@@ -41,7 +41,14 @@ from repro.core.peel import (
     select_bucket,
     workset_sizes,
 )
-from repro.graphstore.structs import DeviceGraph, append_edges, remove_edges
+from repro.graphstore.structs import (
+    DeviceGraph,
+    WindowExpiry,
+    append_edges,
+    expire_window,
+    expiring_edges,
+    remove_edges,
+)
 
 __all__ = [
     "DeviceSpadeState",
@@ -107,24 +114,35 @@ def benign_mask(state: DeviceSpadeState, src, dst, c) -> jax.Array:
 class _SlideBookkeeping(NamedTuple):
     """Replicated pre-re-peel bookkeeping shared by the single-device and
     the mesh-sharded window-slide paths (one definition so the two engines
-    cannot drift — the same role ``compact_slots`` plays for appends)."""
+    cannot drift — the same role ``compact_slots`` plays for appends).
 
-    dropped: jax.Array  # [E] live slots being expired
-    cd: jax.Array  # [E] expired suspiciousness (0 elsewhere)
+    ``dropped``, ``cd``, ``d_src`` and ``d_dst`` are aligned lanes of the
+    pre-tick buffer: its ``[E]`` slots for a drop mask (``d_src`` and
+    ``d_dst`` ``None``: the old graph's own ``src``/``dst``), or the
+    window's span from a :class:`WindowExpiry`'s start."""
+
+    dropped: jax.Array  # live slots being expired
+    cd: jax.Array  # expired suspiciousness (0 elsewhere)
     n_new: jax.Array
     r0: jax.Array
     keep: jax.Array
     prior_g: jax.Array
+    d_src: jax.Array | None = None
+    d_dst: jax.Array | None = None
 
 
 def _slide_prologue(
-    state: DeviceSpadeState, drop: jax.Array | None, src, dst, valid
+    state: DeviceSpadeState, drop: jax.Array | WindowExpiry | None, src,
+    dst, valid
 ) -> _SlideBookkeeping:
     """``drop = None`` marks an insert-only tick at trace time: the dropped
     bookkeeping collapses to inert zeros and the [E]-sized passes over the
-    drop mask are elided from the program entirely."""
+    drop mask are elided from the program entirely.  A
+    :class:`WindowExpiry` reads only the window's span, so expiring a
+    window's oldest tick costs the window, not the buffer."""
     g0 = state.graph
     n_new = jnp.sum(valid).astype(jnp.int32)
+    d_src = d_dst = None
     if drop is None:
         dropped = jnp.zeros(g0.e_capacity, bool)
         n_del = jnp.int32(0)
@@ -132,19 +150,24 @@ def _slide_prologue(
         lvl = _LEVEL_NEW
         comm_loss = jnp.float32(0.0)
     else:
-        dropped = drop & g0.edge_mask
+        if isinstance(drop, WindowExpiry):
+            es, ed, dc, dropped = expiring_edges(g0, drop)
+            d_src, d_dst = es, ed
+        else:
+            es, ed, dc = g0.src, g0.dst, g0.c
+            dropped = drop & g0.edge_mask
         n_del = jnp.sum(dropped).astype(jnp.int32)
-        cd = jnp.where(dropped, g0.c, 0.0)
+        cd = jnp.where(dropped, dc, 0.0)
         # affected suffix start: min endpoint level over dropped AND
         # inserted edges (both endpoint sets sit inside the suffix)
         lvl = jnp.minimum(
-            jnp.min(jnp.where(dropped, state.level[g0.src], _LEVEL_NEW)),
-            jnp.min(jnp.where(dropped, state.level[g0.dst], _LEVEL_NEW)),
+            jnp.min(jnp.where(dropped, state.level[es], _LEVEL_NEW)),
+            jnp.min(jnp.where(dropped, state.level[ed], _LEVEL_NEW)),
         )
         # exact density loss of the old community in the post-deletion
         # graph: the dropped mass with both endpoints inside S^P
-        in_comm = state.community[g0.src] & state.community[g0.dst]
-        comm_loss = jnp.sum(jnp.where(dropped & in_comm, g0.c, 0.0))
+        in_comm = state.community[es] & state.community[ed]
+        comm_loss = jnp.sum(jnp.where(dropped & in_comm, dc, 0.0))
     lvl = jnp.minimum(lvl, jnp.min(jnp.where(valid, state.level[src], _LEVEL_NEW)))
     lvl = jnp.minimum(lvl, jnp.min(jnp.where(valid, state.level[dst], _LEVEL_NEW)))
     r0 = jnp.where((n_del > 0) | (n_new > 0), lvl, _LEVEL_NEW)
@@ -160,7 +183,7 @@ def _slide_prologue(
     )
     return _SlideBookkeeping(
         dropped=dropped, cd=cd, n_new=n_new, r0=r0,
-        keep=state.level >= r0, prior_g=prior_g,
+        keep=state.level >= r0, prior_g=prior_g, d_src=d_src, d_dst=d_dst,
     )
 
 
@@ -185,8 +208,9 @@ def _slide_epilogue(
     ``d_bucket > 0`` (workset dispatch; the host has synced the dropped
     count) compacts the dropped edges into a ``d_bucket``-sized buffer by
     the same searchsorted gather the workset uses, so the decrement
-    scatter-adds O(dropped) updates instead of O(E_capacity) — on a
-    steady-state tick the dropped batch is ~1k lanes of a ~400k buffer.
+    scatter-adds O(dropped) updates instead of one per drop-mask lane —
+    on a steady-state tick the dropped batch is ~1k lanes of a ~400k
+    buffer.  A :class:`WindowExpiry`'s lanes are the window's span alone.
     Identical sums on integer weights; scatter-add order may differ
     otherwise (the same reduction-order caveat as the sharded engine)."""
     g0 = state.graph
@@ -200,22 +224,24 @@ def _slide_epilogue(
     )
     # exact on integer weights; padding lanes carry cd = 0 / cv = 0
     w0 = state.w0
-    if with_drops and d_bucket:
+    d_src = g0.src if bk.d_src is None else bk.d_src
+    d_dst = g0.dst if bk.d_dst is None else bk.d_dst
+    if with_drops and 0 < d_bucket < bk.cd.shape[0]:
         dsum = jnp.cumsum(bk.dropped.astype(jnp.int32))
-        nd = dsum[g0.e_capacity - 1]
+        nd = dsum[-1]
         lane = jnp.arange(d_bucket, dtype=jnp.int32)
         didx = jnp.searchsorted(dsum, lane + 1).astype(jnp.int32)
         dlive = lane < nd
         didx = jnp.where(dlive, didx, 0)
         pad = jnp.int32(g0.n_capacity)  # out of range -> dropped by scatter
-        dsrc = jnp.where(dlive, g0.src[didx], pad)
-        ddst = jnp.where(dlive, g0.dst[didx], pad)
+        dsrc = jnp.where(dlive, d_src[didx], pad)
+        ddst = jnp.where(dlive, d_dst[didx], pad)
         dc = jnp.where(dlive, bk.cd[didx], 0.0)
         w0 = w0.at[dsrc].add(-dc, mode="drop")
         w0 = w0.at[ddst].add(-dc, mode="drop")
     elif with_drops:
-        w0 = w0.at[g0.src].add(-bk.cd, mode="drop")
-        w0 = w0.at[g0.dst].add(-bk.cd, mode="drop")
+        w0 = w0.at[d_src].add(-bk.cd, mode="drop")
+        w0 = w0.at[d_dst].add(-bk.cd, mode="drop")
     cv = jnp.where(valid, c.astype(jnp.float32), 0.0)
     w0 = w0.at[src].add(cv, mode="drop")
     w0 = w0.at[dst].add(cv, mode="drop")
@@ -248,21 +274,37 @@ def tick_counters(counts: np.ndarray, max_rounds: int) -> dict:
             "round_edges": re, "round_slots": rs}
 
 
-def _warm_and_merge(state, g, bk, n_removed, src, dst, c, valid, eps,
-                    max_rounds, counters, with_drops=True):
-    """The fused tick's warm re-peel and merge; with ``counters`` also its
-    int32 counter vector ``[r0, round_vertices..., round_edges...,
-    round_slots...]``."""
-    res = bulk_peel_warm(g, bk.keep, prior_best_g=bk.prior_g, eps=eps,
-                         max_rounds=max_rounds, counters=counters)
+def _merge(state, g, res, bk, n_removed, src, dst, c, valid, counters,
+           **epilogue):
+    """A re-peel's merge into the state (scope ``tick_merge``).  With
+    ``counters``, ``res`` is ``(result, rounds)`` and the tick's int32
+    counter vector ``[r0, round_vertices..., round_edges...,
+    round_slots...]`` comes back beside the state."""
     if counters:
         res, rounds = res
     with jax.named_scope("tick_merge"):
         new = _slide_epilogue(state, g, res, bk, n_removed, src, dst, c,
-                              valid, with_drops=with_drops)
+                              valid, **epilogue)
     if not counters:
         return new
     return new, jnp.concatenate([bk.r0.astype(jnp.int32)[None], *rounds])
+
+
+def _warm_and_merge(state, g, bk, n_removed, src, dst, c, valid, eps,
+                    max_rounds, counters, with_drops=True):
+    """The fused tick's warm re-peel and merge (see :func:`_merge`)."""
+    res = bulk_peel_warm(g, bk.keep, prior_best_g=bk.prior_g, eps=eps,
+                         max_rounds=max_rounds, counters=counters)
+    return _merge(state, g, res, bk, n_removed, src, dst, c, valid, counters,
+                  with_drops=with_drops)
+
+
+def _expire(g: DeviceGraph, drop) -> tuple[DeviceGraph, jax.Array]:
+    """Remove the edges of a drop mask, or a window's oldest tick at the
+    cost of the window (:func:`repro.graphstore.structs.expire_window`)."""
+    if isinstance(drop, WindowExpiry):
+        return expire_window(g, drop)
+    return remove_edges(g, drop)
 
 
 @partial(jax.jit, static_argnames=("eps", "max_rounds", "counters"),
@@ -338,7 +380,7 @@ def delete_and_maintain(
          donate_argnames=("state",))
 def slide_and_maintain(
     state: DeviceSpadeState,
-    drop: jax.Array,
+    drop: jax.Array | WindowExpiry,
     src: jax.Array,
     dst: jax.Array,
     c: jax.Array,
@@ -359,12 +401,15 @@ def slide_and_maintain(
     mass, the best-density tracker is re-seeded with the old community's
     exact post-deletion density (DESIGN.md §6), and the edge counter
     shrinks by the dropped count and grows by the inserted count.
-    ``counters`` and the named scopes as in :func:`insert_and_maintain`.
+    ``drop`` is an ``[E]`` slot mask, or the :class:`WindowExpiry` of a
+    window's oldest tick, whose expiry reads and moves only the window's
+    span.  ``counters`` and the named scopes as in
+    :func:`insert_and_maintain`.
     """
     with jax.named_scope("tick_prologue"):
         bk = _slide_prologue(state, drop, src, dst, valid)
     with jax.named_scope("tick_append"):
-        g, n_removed = remove_edges(state.graph, drop)
+        g, n_removed = _expire(state.graph, drop)
         g = append_edges(g, state.edge_count - n_removed, src, dst, c,
                          valid=valid)
     return _warm_and_merge(state, g, bk, n_removed, src, dst, c, valid,
@@ -404,29 +449,45 @@ class WorksetTickInfo(NamedTuple):
     # fallback — correct, just slower — and re-anchored the predictor
     predicted: bool = False
     miss: bool = False
+    # with ``counters`` (single device): phase B's int32 counter vector, on
+    # device, as the fused tick's (:func:`tick_counters`); its rounds
+    # stream the staged ladder on a fallback, the edge bucket otherwise
+    counts: jax.Array | None = None
+
+
+# Phase A's steps sit under the named scopes ``slide_expire`` (a slide
+# tick's bookkeeping and expiry), ``slide_append`` (an insert tick's
+# bookkeeping, and every tick's append) and ``workset_count``.
 
 
 @jax.jit
 def _insert_phase_a(state, src, dst, c, valid):
-    bk = _slide_prologue(state, None, src, dst, valid)
-    g = append_edges(state.graph, state.edge_count, src, dst, c, valid=valid)
-    nv, ne = workset_sizes(g, bk.keep)
+    with jax.named_scope("slide_append"):
+        bk = _slide_prologue(state, None, src, dst, valid)
+        g = append_edges(state.graph, state.edge_count, src, dst, c,
+                         valid=valid)
+    with jax.named_scope("workset_count"):
+        nv, ne = workset_sizes(g, bk.keep)
     return g, bk, jnp.int32(0), nv, ne
 
 
 @jax.jit
 def _slide_phase_a(state, drop, src, dst, c, valid):
-    bk = _slide_prologue(state, drop, src, dst, valid)
-    g, n_removed = remove_edges(state.graph, drop)
-    g = append_edges(g, state.edge_count - n_removed, src, dst, c, valid=valid)
-    nv, ne = workset_sizes(g, bk.keep)
+    with jax.named_scope("slide_expire"):
+        bk = _slide_prologue(state, drop, src, dst, valid)
+        g, n_removed = _expire(state.graph, drop)
+    with jax.named_scope("slide_append"):
+        g = append_edges(g, state.edge_count - n_removed, src, dst, c,
+                         valid=valid)
+    with jax.named_scope("workset_count"):
+        nv, ne = workset_sizes(g, bk.keep)
     return g, bk, n_removed, nv, ne
 
 
 @partial(
     jax.jit,
     static_argnames=("eps", "max_rounds", "v_bucket", "e_bucket", "use_kernel",
-                     "with_drops", "d_bucket"),
+                     "with_drops", "d_bucket", "counters"),
     donate_argnames=("state", "g"),
 )
 def _phase_b(
@@ -438,24 +499,35 @@ def _phase_b(
     use_kernel: bool = False,
     with_drops: bool = True,
     d_bucket: int = 0,
+    counters: bool = False,
 ):
     """Warm re-peel + state merge.  ``v_bucket/e_bucket = 0`` selects the
-    full-buffer fallback; otherwise the bucketed workset path."""
+    full-buffer fallback; otherwise the bucketed workset path.  With
+    ``counters``, ``(state, counts)`` as :func:`_merge` says."""
     if v_bucket and e_bucket:
         res = bulk_peel_warm_workset(
             g, bk.keep, prior_best_g=bk.prior_g, eps=eps, max_rounds=max_rounds,
             v_bucket=v_bucket, e_bucket=e_bucket, use_kernel=use_kernel,
+            counters=counters,
         )
     else:
         res = bulk_peel_warm(g, bk.keep, prior_best_g=bk.prior_g, eps=eps,
-                             max_rounds=max_rounds, use_kernel=use_kernel)
-    return _slide_epilogue(state, g, res, bk, n_removed, src, dst, c, valid,
-                           with_drops=with_drops, d_bucket=d_bucket)
+                             max_rounds=max_rounds, use_kernel=use_kernel,
+                             counters=counters)
+    return _merge(state, g, res, bk, n_removed, src, dst, c, valid, counters,
+                  with_drops=with_drops, d_bucket=d_bucket)
+
+
+def _unpack(out, counters: bool):
+    """``(state, counts)`` of a phase B that counted, ``(state, None)`` of
+    one that did not."""
+    return out if counters else (out, None)
 
 
 def _dispatch_phase_b(
     state, g, bk, n_removed, src, dst, c, valid,
     nv, ne, eps, max_rounds, use_kernel, min_bucket, with_drops=True,
+    counters=False,
 ) -> tuple[DeviceSpadeState, WorksetTickInfo]:
     n_cap, e_cap = state.graph.n_capacity, state.graph.e_capacity
     # the tick's only device->host sync: three scalars, one transfer
@@ -474,12 +546,14 @@ def _dispatch_phase_b(
     bd = 0
     if with_drops:
         bd = select_bucket(nd_i, e_cap, floor=min_bucket) or 0
-    new_state = _phase_b(
+    new_state, counts = _unpack(_phase_b(
         state, g, bk, n_removed, src, dst, c, valid,
         eps=eps, max_rounds=max_rounds, v_bucket=bv, e_bucket=be,
         use_kernel=use_kernel, with_drops=with_drops, d_bucket=bd,
-    )
-    return new_state, WorksetTickInfo(nv_i, ne_i, bv, be, not (bv and be))
+        counters=counters,
+    ), counters)
+    return new_state, WorksetTickInfo(nv_i, ne_i, bv, be, not (bv and be),
+                                      counts=counts)
 
 
 def insert_and_maintain_auto(
@@ -492,21 +566,24 @@ def insert_and_maintain_auto(
     max_rounds: int = 0,
     use_kernel: bool = False,
     min_bucket: int = 64,
+    counters: bool = False,
 ) -> tuple[DeviceSpadeState, WorksetTickInfo]:
     """:func:`insert_and_maintain` through the workset engine.
 
     Two device programs + one scalar sync per tick; bit-identical to the
     fused path on integer weights (workset or fallback alike).
+    ``counters`` (a bounded peel) puts phase B's counter vector in
+    ``WorksetTickInfo.counts``.
     """
     g, bk, n_removed, nv, ne = _insert_phase_a(state, src, dst, c, valid)
     return _dispatch_phase_b(state, g, bk, n_removed, src, dst, c, valid,
                              nv, ne, eps, max_rounds, use_kernel, min_bucket,
-                             with_drops=False)
+                             with_drops=False, counters=counters)
 
 
 def slide_and_maintain_auto(
     state: DeviceSpadeState,
-    drop: jax.Array,
+    drop: jax.Array | WindowExpiry,
     src: jax.Array,
     dst: jax.Array,
     c: jax.Array,
@@ -515,12 +592,14 @@ def slide_and_maintain_auto(
     max_rounds: int = 0,
     use_kernel: bool = False,
     min_bucket: int = 64,
+    counters: bool = False,
 ) -> tuple[DeviceSpadeState, WorksetTickInfo]:
     """:func:`slide_and_maintain` through the workset engine (also covers
     pure deletion: pass an all-False ``valid``)."""
     g, bk, n_removed, nv, ne = _slide_phase_a(state, drop, src, dst, c, valid)
     return _dispatch_phase_b(state, g, bk, n_removed, src, dst, c, valid,
-                             nv, ne, eps, max_rounds, use_kernel, min_bucket)
+                             nv, ne, eps, max_rounds, use_kernel, min_bucket,
+                             counters=counters)
 
 
 # ---------------------------------------------------------------------------
@@ -585,7 +664,7 @@ class BucketPredictor:
 @partial(
     jax.jit,
     static_argnames=("eps", "max_rounds", "v_bucket", "e_bucket", "use_kernel",
-                     "with_drops", "d_bucket"),
+                     "with_drops", "d_bucket", "counters"),
     donate_argnames=("state", "g"),
 )
 def _phase_b_checked(
@@ -597,26 +676,30 @@ def _phase_b_checked(
     use_kernel: bool = False,
     with_drops: bool = True,
     d_bucket: int = 0,
+    counters: bool = False,
 ):
     """Phase B with predicted buckets: the workset/full-buffer choice moves
     onto the device (``lax.cond`` on the actual counts), so dispatch needs
-    no host-resident count."""
+    no host-resident count.  Returns ``(out, fits)``, ``out`` as
+    :func:`_phase_b`'s."""
     res, fits = bulk_peel_warm_checked(
         g, bk.keep, bk.prior_g, nv, ne, eps=eps, max_rounds=max_rounds,
         v_bucket=v_bucket, e_bucket=e_bucket, use_kernel=use_kernel,
+        counters=counters,
     )
-    return _slide_epilogue(state, g, res, bk, n_removed, src, dst, c, valid,
-                           with_drops=with_drops, d_bucket=d_bucket), fits
+    return _merge(state, g, res, bk, n_removed, src, dst, c, valid, counters,
+                  with_drops=with_drops, d_bucket=d_bucket), fits
 
 
 def _predictive_dispatch_core(
     state, nv, ne, predictor: BucketPredictor, with_drops, n_dropped,
-    *, synced, checked, full,
+    *, synced, checked, full, counters=False,
 ) -> tuple[DeviceSpadeState, WorksetTickInfo]:
     """Predictor-driven phase-B dispatch, shared by the single-device and
     mesh-sharded engines (they differ only in the three phase-B callables:
     ``synced(with_drops)``, ``checked(bv, be, wd, bd)``,
-    ``full(wd, bd)``).
+    ``full(wd, bd)``; with ``counters`` the last two return ``(state,
+    counts)``).
 
     Counts are fetched only *after* dispatch.  ``n_dropped`` is the host's
     (upper bound on the) number of live edges in the drop mask — the
@@ -637,9 +720,10 @@ def _predictive_dispatch_core(
                            floor=predictor.min_bucket) or 0
     bv, be = pred
     if bv and be:
-        new_state, _fits = checked(bv, be, wd, bd)
+        out, _fits = checked(bv, be, wd, bd)
     else:  # recent suffixes outgrew the ladder: full-buffer, no check
-        new_state = full(wd, bd)
+        out = full(wd, bd)
+    new_state, counts = _unpack(out, counters)
     # drained AFTER dispatch: the transfer overlaps phase B instead of
     # gating it — feeds the next prediction and the telemetry only
     nv_i, ne_i = (int(x) for x in np.asarray(jnp.stack([nv, ne])))
@@ -652,13 +736,14 @@ def _predictive_dispatch_core(
         fallback=not hit,
         predicted=True,
         miss=bool(bv and be) and not hit,
+        counts=counts,
     )
 
 
 def _predictive_dispatch(
     state, g, bk, n_removed, src, dst, c, valid, nv, ne,
     predictor: BucketPredictor, eps, max_rounds, use_kernel,
-    with_drops=True, n_dropped=None,
+    with_drops=True, n_dropped=None, counters=False,
 ) -> tuple[DeviceSpadeState, WorksetTickInfo]:
     """Single-device binding of :func:`_predictive_dispatch_core`."""
     return _predictive_dispatch_core(
@@ -666,17 +751,21 @@ def _predictive_dispatch(
         synced=lambda wd: _dispatch_phase_b(
             state, g, bk, n_removed, src, dst, c, valid, nv, ne,
             eps, max_rounds, use_kernel, predictor.min_bucket, with_drops=wd,
+            counters=counters,
         ),
         checked=lambda bv, be, wd, bd: _phase_b_checked(
             state, g, bk, n_removed, nv, ne, src, dst, c, valid,
             eps=eps, max_rounds=max_rounds, v_bucket=bv, e_bucket=be,
             use_kernel=use_kernel, with_drops=wd, d_bucket=bd,
+            counters=counters,
         ),
         full=lambda wd, bd: _phase_b(
             state, g, bk, n_removed, src, dst, c, valid,
             eps=eps, max_rounds=max_rounds, v_bucket=0, e_bucket=0,
             use_kernel=use_kernel, with_drops=wd, d_bucket=bd,
+            counters=counters,
         ),
+        counters=counters,
     )
 
 
@@ -690,20 +779,23 @@ def insert_and_maintain_predictive(
     eps: float = 0.1,
     max_rounds: int = 0,
     use_kernel: bool = False,
+    counters: bool = False,
 ) -> tuple[DeviceSpadeState, WorksetTickInfo]:
     """:func:`insert_and_maintain_auto` without the blocking count sync:
     buckets are predicted from ``predictor``'s history and checked on
     device.  Bit-identical results to the synced/fused paths on integer
-    weights (bucket choice never changes the math, only the cost)."""
+    weights (bucket choice never changes the math, only the cost).
+    ``counters`` as in :func:`insert_and_maintain_auto`."""
     g, bk, n_removed, nv, ne = _insert_phase_a(state, src, dst, c, valid)
     return _predictive_dispatch(state, g, bk, n_removed, src, dst, c, valid,
                                 nv, ne, predictor, eps, max_rounds, use_kernel,
-                                with_drops=False, n_dropped=0)
+                                with_drops=False, n_dropped=0,
+                                counters=counters)
 
 
 def slide_and_maintain_predictive(
     state: DeviceSpadeState,
-    drop: jax.Array,
+    drop: jax.Array | WindowExpiry,
     src: jax.Array,
     dst: jax.Array,
     c: jax.Array,
@@ -713,16 +805,18 @@ def slide_and_maintain_predictive(
     eps: float = 0.1,
     max_rounds: int = 0,
     use_kernel: bool = False,
+    counters: bool = False,
 ) -> tuple[DeviceSpadeState, WorksetTickInfo]:
     """:func:`slide_and_maintain_auto` without the blocking count sync.
 
-    ``n_dropped``: host-known upper bound on the live edges in ``drop``
-    (the windowed service's ring count is exact); ``None`` keeps the
-    full-width w0 decrement."""
+    ``n_dropped``: host-known upper bound on the live edges in a drop
+    mask (the windowed service's ring count is exact); ``None`` keeps the
+    decrement over every lane of ``drop`` (a :class:`WindowExpiry` has
+    the window's span of them)."""
     g, bk, n_removed, nv, ne = _slide_phase_a(state, drop, src, dst, c, valid)
     return _predictive_dispatch(state, g, bk, n_removed, src, dst, c, valid,
                                 nv, ne, predictor, eps, max_rounds, use_kernel,
-                                n_dropped=n_dropped)
+                                n_dropped=n_dropped, counters=counters)
 
 
 @partial(jax.jit, static_argnames=("eps",))

@@ -366,12 +366,10 @@ def bulk_peel(
     )
 
 
-def _run_rounds(round_fn, init, max_rounds: int, unroll: bool = False):
-    if unroll and max_rounds:
-        s = init
-        for _ in range(max_rounds):
-            s = round_fn(s)
-        return s
+def _run_rounds(round_fn, init, max_rounds: int):
+    """Rounds over one fixed buffer: ``max_rounds`` of them, or until the
+    set is empty (the sharded engine's; single-device peels use
+    :func:`_run_stages`)."""
     if max_rounds and max_rounds > 0:
         return jax.lax.fori_loop(0, max_rounds, lambda i, s: round_fn(s), init)
     return jax.lax.while_loop(lambda s: s.n_act > 0, round_fn, init)
@@ -671,8 +669,8 @@ def _gather_workset(
 
 @partial(
     jax.jit,
-    static_argnames=("eps", "max_rounds", "unroll", "v_bucket", "e_bucket",
-                     "use_kernel"),
+    static_argnames=("eps", "max_rounds", "v_bucket", "e_bucket",
+                     "use_kernel", "counters"),
 )
 def bulk_peel_warm_workset(
     g: DeviceGraph,
@@ -680,12 +678,12 @@ def bulk_peel_warm_workset(
     prior_best_g: jax.Array,
     eps: float = 0.1,
     max_rounds: int = 0,
-    unroll: bool = False,
     *,
     v_bucket: int,
     e_bucket: int,
     use_kernel: bool = False,
-) -> PeelResultDevice:
+    counters: bool = False,
+):
     """Workset twin of :func:`bulk_peel_warm`: gather → peel → scatter.
 
     Bit-identical to the full-buffer warm peel on integer weights: the
@@ -693,7 +691,10 @@ def bulk_peel_warm_workset(
     edges, every per-vertex/per-set quantity is the same integer sum (f32
     sums of integers are exact in any order), and the round sequence is
     driven by those quantities only.  See DESIGN.md §8 for the correctness
-    argument across the scatter-back.
+    argument across the scatter-back.  The rounds are :func:`_run_stages`
+    over the bucket, so they stop once the set is empty, and
+    ``counters`` returns ``(result, counts)`` as :func:`bulk_peel_warm`
+    does, with the bucket's ladder in ``round_slots``.
     """
     V = g.n_capacity
     ws = _gather_workset(g, keep, v_bucket, e_bucket)
@@ -713,15 +714,13 @@ def bulk_peel_warm_workset(
         best_level=jnp.int32(0),
         round_=jnp.int32(0),
     )
-    state = _run_rounds(
-        partial(_round_step, ws.src, ws.dst, ws.c, ws.a, eps, use_kernel),
-        init, max_rounds, unroll,
-    )
+    state, counts = _run_stages(ws.src, ws.dst, ws.c, ws.a, eps, use_kernel,
+                                init, max_rounds, counters)
     # scatter the suffix results back to full-width vertex arrays; pad
     # lanes carry vid = V and are dropped
     level = jnp.full(V, -1, jnp.int32).at[ws.vid].set(state.level, mode="drop")
     delta = jnp.zeros(V, jnp.float32).at[ws.vid].set(state.w, mode="drop")
-    return PeelResultDevice(
+    res = PeelResultDevice(
         level=level,
         best_level=state.best_level,
         best_g=state.best_g,
@@ -729,11 +728,13 @@ def bulk_peel_warm_workset(
         order=jnp.zeros(V, jnp.int32),
         delta=delta,
     )
+    return (res, counts) if counters else res
 
 
 @partial(
     jax.jit,
-    static_argnames=("eps", "max_rounds", "v_bucket", "e_bucket", "use_kernel"),
+    static_argnames=("eps", "max_rounds", "v_bucket", "e_bucket", "use_kernel",
+                     "counters"),
 )
 def bulk_peel_warm_checked(
     g: DeviceGraph,
@@ -747,7 +748,8 @@ def bulk_peel_warm_checked(
     v_bucket: int,
     e_bucket: int,
     use_kernel: bool = False,
-) -> tuple[PeelResultDevice, jax.Array]:
+    counters: bool = False,
+):
     """Warm peel with a *device-side* bucket-fit check — the primitive the
     predictive workset dispatcher builds on.
 
@@ -763,7 +765,8 @@ def bulk_peel_warm_checked(
     never correctness.
 
     Returns ``(result, fits)`` with ``fits`` the device bool the caller
-    can drain lazily for telemetry.
+    can drain lazily for telemetry; with ``counters``, ``result`` is
+    ``(result, counts)`` from whichever branch ran.
     """
     fits = (nv <= jnp.int32(v_bucket)) & (ne <= jnp.int32(e_bucket))
     res = jax.lax.cond(
@@ -771,10 +774,11 @@ def bulk_peel_warm_checked(
         lambda: bulk_peel_warm_workset(
             g, keep, prior_best_g, eps=eps, max_rounds=max_rounds,
             v_bucket=v_bucket, e_bucket=e_bucket, use_kernel=use_kernel,
+            counters=counters,
         ),
         lambda: bulk_peel_warm(
             g, keep, prior_best_g, eps=eps, max_rounds=max_rounds,
-            use_kernel=use_kernel,
+            use_kernel=use_kernel, counters=counters,
         ),
     )
     return res, fits

@@ -15,9 +15,12 @@ from .segment_ops import (
 )
 from .structs import (
     DeviceGraph,
+    WindowExpiry,
     append_edges,
     csr_sort,
     device_graph_from_coo,
+    expire_window,
+    expiring_edges,
     remove_edges,
 )
 
@@ -26,6 +29,9 @@ __all__ = [
     "device_graph_from_coo",
     "append_edges",
     "remove_edges",
+    "WindowExpiry",
+    "expiring_edges",
+    "expire_window",
     "csr_sort",
     "segment_sum",
     "segment_mean",

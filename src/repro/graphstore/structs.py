@@ -18,8 +18,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["DeviceGraph", "device_graph_from_coo", "compact_slots",
-           "append_edges", "remove_edges", "csr_sort"]
+__all__ = ["DeviceGraph", "WindowExpiry", "device_graph_from_coo",
+           "compact_slots", "append_edges", "remove_edges", "expiring_edges",
+           "expire_window", "csr_sort"]
 
 
 def compact_slots(
@@ -157,9 +158,11 @@ def remove_edges(g: DeviceGraph, drop: jax.Array) -> tuple[DeviceGraph, jax.Arra
     The k-th surviving edge (in slot order) moves to slot ``k`` — the same
     slot semantics as ``compact_slots`` on the append path, so insertion
     order is preserved and the live region stays a prefix.  Sliding-window
-    callers exploit this: after every expiry the oldest batch is again the
-    first ``count`` slots.  Freed slots revert to the standard inert
-    padding (``src = dst = n_capacity - 1``, ``c = 0``, mask False).
+    callers exploit this: after every expiry the oldest batch again sits
+    right after the base graph, where :func:`expire_window` expires it
+    without reading the rest of the buffer.  Freed slots revert to the
+    standard inert padding (``src = dst = n_capacity - 1``, ``c = 0``,
+    mask False).
 
     The compaction runs as a **gather**: output slot ``k`` pulls the k-th
     survivor, located by binary search over the survivor-count prefix sum
@@ -192,6 +195,86 @@ def remove_edges(g: DeviceGraph, drop: jax.Array) -> tuple[DeviceGraph, jax.Arra
             edge_mask=live,
         ),
         n_removed,
+    )
+
+
+@partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["start", "count"],
+    meta_fields=["span"],
+)
+@dataclasses.dataclass(frozen=True)
+class WindowExpiry:
+    """The oldest tick of a sliding window: the ``count`` slots from
+    ``start`` of a buffer whose live edges are a prefix.
+
+    ``start`` and ``count`` are traced int32 scalars: the base graph's
+    edge count and the oldest tick's.  ``span`` is static, the window's
+    resident slots (``window_ticks * batch_edges``), so a program that
+    takes an expiry compiles once per configuration, whatever edge count
+    the base graph has.  The live edges end within ``span`` slots of
+    ``start``, ``count <= span``, and the buffer holds ``start + count +
+    span`` slots: the windowed service's capacity ``m_base +
+    (window_ticks + 1) * batch_edges`` does."""
+
+    start: jax.Array  # int32 scalar
+    count: jax.Array  # int32 scalar
+    span: int
+
+
+def _window_slice(a: jax.Array, start: jax.Array, span: int) -> jax.Array:
+    return jax.lax.dynamic_slice_in_dim(a, start, span)
+
+
+def expiring_edges(
+    g: DeviceGraph, x: WindowExpiry
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """``(src, dst, c, dropped)`` of the ``x.span`` slots from
+    ``x.start``; ``dropped`` marks the live edges among the first
+    ``x.count``, the edges that expire."""
+    lane = jnp.arange(x.span, dtype=jnp.int32)
+    mask = _window_slice(g.edge_mask, x.start, x.span)
+    return (_window_slice(g.src, x.start, x.span),
+            _window_slice(g.dst, x.start, x.span),
+            _window_slice(g.c, x.start, x.span), (lane < x.count) & mask)
+
+
+def expire_window(
+    g: DeviceGraph, x: WindowExpiry
+) -> tuple[DeviceGraph, jax.Array]:
+    """``remove_edges`` of the slots ``[x.start, x.start + x.count)``, at
+    the cost of the window.
+
+    The live edges are a prefix that ends within ``x.span`` slots of
+    ``x.start``, so the survivors past the expired slots are the span
+    from ``x.start + x.count``: shifting it down by ``x.count`` puts each
+    where ``remove_edges`` would, in the same order, and its dead lanes
+    take the inert padding.  No slot before ``x.start`` or past the span
+    is read or written.  Slot for slot equal to ``remove_edges`` on the
+    same mask.
+
+    Returns ``(graph, n_removed)``, the live edges the expired slots held.
+    """
+    shift = x.start + x.count
+    alive = _window_slice(g.edge_mask, shift, x.span)
+    pad = jnp.int32(g.n_capacity - 1)
+
+    def put(a, v):
+        return jax.lax.dynamic_update_slice_in_dim(a, v, x.start, 0)
+
+    *_, dropped = expiring_edges(g, x)
+    return (
+        dataclasses.replace(
+            g,
+            src=put(g.src, jnp.where(alive, _window_slice(g.src, shift,
+                                                          x.span), pad)),
+            dst=put(g.dst, jnp.where(alive, _window_slice(g.dst, shift,
+                                                          x.span), pad)),
+            c=put(g.c, jnp.where(alive, _window_slice(g.c, shift, x.span),
+                                 0.0)),
+            edge_mask=put(g.edge_mask, alive),
+        ),
+        jnp.sum(dropped, dtype=jnp.int32),
     )
 
 

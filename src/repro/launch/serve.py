@@ -100,9 +100,9 @@ def main() -> None:
               f"ws/fb={rep.n_workset_ticks}/{rep.n_fallback_ticks} "
               f"pred/miss={rep.n_predicted_ticks}/{rep.n_bucket_miss_ticks}")
         if rep.round_vertices is not None and rep.round_vertices.size:
-            # the fused engine's counters: rounds that started with an
-            # empty restricted set, and live edges and streamed slots over
-            # the slots full-buffer rounds would read
+            # the single-device engines' counters: rounds that started
+            # with an empty restricted set, and live edges and streamed
+            # slots over the slots full-buffer rounds would read
             dead = int((rep.round_vertices == 0).sum())
             full = rep.round_edges.size * rep.edge_slots
             live = rep.round_edges.sum() / full

@@ -26,9 +26,16 @@ The device serving loop here is the production tick pipeline:
 * maintenance through the fused, workset, or predictive-workset engine,
   single-device or mesh-sharded,
 * per-tick statistics accumulated on device and drained at shutdown; the
-  single-device fused engine also returns its per-round counters
+  single-device engines also return their per-round counters
   (:func:`repro.core.incremental.tick_counters`), fetched once after the
   loop.
+
+Under a window the oldest resident tick sits right after the base graph,
+so a single-device slide expires it as a
+:class:`repro.graphstore.structs.WindowExpiry`: the base count and the
+tick's count ride as traced scalars and the span is the spec's, so each
+tick program compiles once per configuration whatever the base graph
+holds.
 
 Every step of the loop runs under a ``jax.profiler.TraceAnnotation``
 span, so a profiler trace puts the host's steps on the device trace's
@@ -89,7 +96,7 @@ from repro.dist.graph import (
     sharded_slide_and_maintain_predictive,
 )
 from repro.graphstore.generators import TxStream
-from repro.graphstore.structs import device_graph_from_coo
+from repro.graphstore.structs import WindowExpiry, device_graph_from_coo
 
 __all__ = ["EngineSpec", "SpadeService", "DeviceServiceReport"]
 
@@ -174,16 +181,17 @@ class DeviceServiceReport:
     # predictive-selector telemetry (zeros when predictive=False)
     n_predicted_ticks: int = 0  # ticks dispatched without a count sync
     n_bucket_miss_ticks: int = 0  # predicted buckets the suffix outgrew
-    # the single-device fused engine's counters, one row per tick (None
-    # for the workset, predictive and mesh engines and for an unbounded
-    # peel): the restricted set's active vertices and live edges at the
-    # start of each round, [n_ticks, max_rounds]; a round starting with
-    # no active vertex peels nothing.  ``round_slots``, [n_ticks,
-    # max_rounds]: the edge slots each round streamed, a size of the
-    # rounds' ladder (``core.peel.edge_ladder``), 0 where no round ran.
-    # Per tick the suffix start r0 and the suffix's vertices and induced
-    # live edges (round 0), [n_ticks]; ``edge_slots`` is the edge
-    # buffer's capacity, which the first stage of rounds streams.
+    # the single-device engines' counters, one row per tick (None for the
+    # mesh engines and for an unbounded peel): the restricted set's
+    # active vertices and live edges at the start of each round,
+    # [n_ticks, max_rounds]; a round starting with no active vertex
+    # peels nothing.  ``round_slots``, [n_ticks, max_rounds]: the edge
+    # slots each round streamed, a size of the rounds' ladder
+    # (``core.peel.edge_ladder``; a workset tick's ladder starts at its
+    # edge bucket), 0 where no round ran.  Per tick the suffix start r0
+    # and the suffix's vertices and induced live edges (round 0),
+    # [n_ticks]; ``edge_slots`` is the edge buffer's capacity, which the
+    # first stage of a fused or fallback tick's rounds streams.
     round_vertices: np.ndarray | None = None
     round_edges: np.ndarray | None = None
     round_slots: np.ndarray | None = None
@@ -260,6 +268,12 @@ def _accum_detected(ever, community):
     return ever | community
 
 
+@partial(jax.jit, static_argnums=0)
+def _batch_weights(sem: SuspSemantics, in_deg, src, dst, raw, valid, aux):
+    """The semantics' streamed-tick rule, compiled once per semantics."""
+    return sem.batch_weights(in_deg, src, dst, raw, valid, aux)
+
+
 def _run_device_service(
     stream: TxStream, sem: SuspSemantics, spec: EngineSpec
 ) -> DeviceServiceReport:
@@ -297,8 +311,8 @@ def _run_device_service(
             g = shard_graph(g, mesh, axis=shard_axis)
     predictive = spec.workset and spec.predictive
     predictor = None
-    # the single-device fused engine counts its rounds (a bounded peel)
-    counted = mesh is None and not spec.workset and max_rounds > 0
+    # the single-device engines count their rounds (a bounded peel)
+    counted = mesh is None and max_rounds > 0
     if mesh is not None:
         with TraceAnnotation("spade.initial_peel"):
             state = init_sharded_state(g, mesh, axis=shard_axis, eps=eps)
@@ -346,9 +360,6 @@ def _run_device_service(
         if deg_dev.shape[0] < g.n_capacity:
             deg_dev = jnp.pad(deg_dev, (0, g.n_capacity - deg_dev.shape[0]))
 
-    # the semantics' streamed-tick rule, compiled once for the whole run
-    weight_fn = jax.jit(sem.batch_weights)
-
     n_inc = stream.inc_src.shape[0]
     n_ticks = 0
     n_refresh = 0
@@ -365,7 +376,11 @@ def _run_device_service(
     ever_detected = jnp.zeros(g.n_capacity, bool)  # vertices ever in S^P
     counts: list[jax.Array] = []  # per-tick counter vectors, on device
     tick_kw = {"counters": True} if counted else {}
-    slot_ids = jnp.arange(g.e_capacity, dtype=jnp.int32)
+    if mesh is None:
+        # traced, so that no program is keyed on the base graph's size
+        base_count = jnp.int32(m_base)
+    else:
+        slot_ids = jnp.arange(g.e_capacity, dtype=jnp.int32)
     for i in range(0, n_inc, batch_edges):
         with TraceAnnotation("spade.tick", tick=n_ticks):
             j = min(i + batch_edges, n_inc)
@@ -390,8 +405,8 @@ def _run_device_service(
                     aux = np.concatenate([time_i, np.zeros(pad)])
                     aux_d = jnp.asarray(aux, jnp.float32)
             with TraceAnnotation("spade.weigh"):
-                w, deg_dev = weight_fn(deg_dev, bs_d, bd_d, amt_d, valid_d,
-                                       aux_d)
+                w, deg_dev = _batch_weights(sem, deg_dev, bs_d, bd_d, amt_d,
+                                            valid_d, aux_d)
                 benign_acc = _accum_benign(benign_acc, state, bs_d, bd_d, w,
                                            valid_d)
             t0 = time.perf_counter()
@@ -403,8 +418,14 @@ def _run_device_service(
                     # the oldest resident batch always sits right after the
                     # base graph.
                     cnt0 = ring.pop(0)
-                    drop = (slot_ids >= m_base) & (slot_ids < m_base + cnt0)
-                    kw = {"n_dropped": cnt0} if predictive else {}
+                    kw = {}
+                    if mesh is None:
+                        drop = WindowExpiry(base_count, jnp.int32(cnt0),
+                                            window_ticks * batch_edges)
+                    else:
+                        drop = (slot_ids >= m_base) \
+                            & (slot_ids < m_base + cnt0)
+                        kw = {"n_dropped": cnt0} if predictive else {}
                     out = slide(
                         state, drop, bs_d, bd_d, w.astype(jnp.float32),
                         valid_d, eps=eps, max_rounds=max_rounds, **kw,
@@ -418,6 +439,8 @@ def _run_device_service(
                     )
             if spec.workset:
                 state, info = out
+                if info.counts is not None:
+                    counts.append(info.counts)
             elif isinstance(out, tuple):  # the counted tick: (state, counts)
                 state, cnt = out
                 counts.append(cnt)

@@ -1,13 +1,17 @@
-"""The fused tick's round counters, its named scopes, and the served
+"""The tick programs' round counters, their named scopes, and the served
 loop's ``spade.*`` profiler spans.
 
 Counters are checked against a brute-force numpy recount: the same
 bounded warm re-peel, replayed round by round on the host from the
 pre-tick levels and the post-append edge buffer, on integer weights (so
-every f32 sum is exact and the two peels take the same rounds).
+every f32 sum is exact and the two peels take the same rounds).  The
+predictive workset engine's counters are checked against the fused
+engine's on the same window stream.
 """
 
 from __future__ import annotations
+
+import dataclasses
 
 import numpy as np
 import pytest
@@ -240,7 +244,72 @@ def test_served_loop_spans_in_a_profiler_trace(tmp_path):
 
 
 def test_workset_engine_leaves_the_counters_empty():
+    """Under an unbounded peel the workset engine counts nothing; under a
+    bounded one it counts every tick, as the fused engine does."""
     stream = make_transaction_stream(n=600, m=3000, seed=2)
-    rep = SpadeService("DG", EngineSpec(batch_edges=128, max_rounds=6,
+    rep = SpadeService("DG", EngineSpec(batch_edges=128, max_rounds=0,
                                         workset=True)).run(stream)
     assert rep.round_vertices is None and rep.edge_slots is None
+    rep = SpadeService("DG", EngineSpec(batch_edges=128, max_rounds=6,
+                                        workset=True)).run(stream)
+    assert rep.round_vertices.shape == rep.round_slots.shape \
+        == (rep.n_ticks, 6)
+    assert rep.edge_slots > 0
+
+
+@pytest.mark.parametrize("stream_kw, window", [
+    (dict(n=600, m=3000, seed=2), 3),
+    (dict(n=3000, m=15000, seed=9, inc_fraction=0.0), 6),
+], ids=["fallback", "workset"])
+def test_predictive_counters_match_the_fused_engine(stream_kw, window):
+    """On one window stream the predictive engine's rounds see what the
+    fused engine's see, row for row.  A fallback tick streams the fused
+    tick's ladder; a workset tick streams its edge bucket; a round that
+    did not run counts 0.  The background stream reaches level-0 accounts
+    and falls back every tick; the planted burst alone takes the workset
+    once the predictor has seen small suffixes."""
+    stream = make_transaction_stream(**stream_kw)
+    spec = EngineSpec(batch_edges=32, max_rounds=8, window_ticks=window)
+    fused = SpadeService("DG", spec).run(stream)
+    pred = SpadeService("DG", dataclasses.replace(spec, workset=True)).run(
+        stream)
+    assert pred.n_ticks == fused.n_ticks > window
+    assert pred.n_expired_edges == fused.n_expired_edges > 0
+    for f in ("round_vertices", "round_edges", "suffix_r0", "suffix_vertices",
+              "suffix_edges", "edge_slots"):
+        np.testing.assert_array_equal(getattr(pred, f), getattr(fused, f),
+                                      err_msg=f)
+    rs, ran = pred.round_slots, pred.round_vertices > 0
+    assert ((rs > 0) == ran).all()
+    on_bucket = (rs != fused.round_slots).any(axis=1)
+    assert on_bucket.sum() == pred.n_workset_ticks
+    for row, r in zip(rs[on_bucket], ran[on_bucket]):
+        bucket = int(row[0])
+        assert bucket & (bucket - 1) == 0 and bucket <= pred.edge_slots // 2
+        assert set(row[r]) <= set(edge_ladder(bucket))
+    if stream_kw.get("inc_fraction") == 0.0:
+        assert pred.n_workset_ticks > 0 and pred.n_fallback_ticks > 0
+    else:
+        assert pred.n_workset_ticks == 0
+
+
+def test_phase_a_carries_the_named_scopes():
+    """The workset engine's phase A names its expiry, its append and the
+    suffix count; a window's expiry keeps the module's name."""
+    from repro.core.incremental import _insert_phase_a, _slide_phase_a
+    from repro.graphstore.structs import WindowExpiry
+
+    rng = np.random.default_rng(6)
+    state = init_state(_graph(rng), eps=EPS)
+    src_b, dst_b, c_b, valid = _batch(rng, 120)
+    batch = (jnp.asarray(src_b), jnp.asarray(dst_b), jnp.asarray(c_b),
+             jnp.asarray(valid))
+    expiry = WindowExpiry(jnp.int32(500), jnp.int32(64), 4 * 64)
+    lowered = _slide_phase_a.lower(state, expiry, *batch)
+    hlo = lowered.compile().as_text()
+    for scope in ("slide_expire", "slide_append", "workset_count"):
+        assert f"/{scope}/" in hlo, scope
+    assert "jit__slide_phase_a" in lowered.as_text()
+    hlo = _insert_phase_a.lower(state, *batch).compile().as_text()
+    for scope in ("slide_append", "workset_count"):
+        assert f"/{scope}/" in hlo, scope

@@ -8,7 +8,8 @@ through the incremental engines and checked against from-scratch oracles:
   (order and peel-time weights) after every operation; ``Spade`` with
   edge grouping must do the same at every flush point.
 * **device plane** — the windowed replay (the fused ``slide_and_maintain``
-  service tick alternated with composed ``delete_and_maintain`` +
+  service tick, expiring the oldest tick as the service does with a
+  ``WindowExpiry``, alternated with composed ``delete_and_maintain`` +
   ``insert_and_maintain``, under the service's slot bookkeeping) must track
   the host-mirrored window edge multiset and ``w0`` bit-exactly (integer
   weights), report a community whose density upper-bounds ``best_g`` and
@@ -26,7 +27,11 @@ deterministic by construction.
 
 from __future__ import annotations
 
+import dataclasses
+import importlib.util
 import itertools
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -36,16 +41,22 @@ try:
 except ImportError:  # container may lack hypothesis; stub runner takes over
     from _hypothesis_stub import given, settings, st
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.incremental import (
+    BucketPredictor,
+    _phase_b,
+    _slide_prologue,
     delete_and_maintain,
     full_refresh,
     init_state,
     insert_and_maintain,
     insert_and_maintain_auto,
+    insert_and_maintain_predictive,
     slide_and_maintain,
     slide_and_maintain_auto,
+    slide_and_maintain_predictive,
 )
 from repro.core.peel import (
     bulk_peel,
@@ -63,7 +74,15 @@ from repro.core.reference import (
     static_peel,
 )
 from repro.core.spade import Spade
-from repro.graphstore.structs import device_graph_from_coo
+from repro.graphstore.generators import make_transaction_stream
+from repro.graphstore.structs import (
+    WindowExpiry,
+    append_edges,
+    device_graph_from_coo,
+    expire_window,
+    remove_edges,
+)
+from repro.serve import EngineSpec, SpadeService
 
 N = 10  # vertex universe: small enough to brute-force optimal density
 V_CAP, E_CAP = 16, 96  # fixed capacities -> one jit compilation per engine
@@ -200,7 +219,10 @@ def run_window_differential(base, ticks, window):
     buffers, re-peel the workset only, scatter back — with automatic
     full-buffer fallback) and must stay bit-identical to the fused
     full-buffer path; the tiny ``min_bucket`` makes the replay cross
-    bucket boundaries and exercise workset and fallback ticks alike."""
+    bucket boundaries and exercise workset and fallback ticks alike.  A
+    third state takes the served predictive engine, expiring each oldest
+    tick as a ``WindowExpiry`` (traced base count, static span), and must
+    stay bit-identical too."""
     B = 4  # fixed padded batch size -> stable jit shapes
     src = np.array([e[0] for e in base], np.int64)
     dst = np.array([e[1] for e in base], np.int64)
@@ -210,6 +232,8 @@ def run_window_differential(base, ticks, window):
     )
     state = init_state(mk(), eps=EPS)
     state_ws = init_state(mk(), eps=EPS)  # independent buffers (donation)
+    state_pr = init_state(mk(), eps=EPS)
+    predictor = BucketPredictor(V_CAP, E_CAP, min_bucket=4)
     m_base = len(base)
     ring: list[list[tuple[int, int, int]]] = []
     slot_ids = jnp.arange(E_CAP, dtype=jnp.int32)
@@ -220,6 +244,8 @@ def run_window_differential(base, ticks, window):
     for t, batch in enumerate(ticks):
         n_exp = len(ring.pop(0)) if len(ring) >= window else 0
         drop = (slot_ids >= m_base) & (slot_ids < m_base + n_exp)
+        expiry = WindowExpiry(jnp.int32(m_base), jnp.int32(n_exp),
+                              window * B)
         bs = np.zeros(B, np.int32)
         bd = np.zeros(B, np.int32)
         bc = np.zeros(B, np.float32)
@@ -231,9 +257,13 @@ def run_window_differential(base, ticks, window):
         # alternate the fused service tick and the composed ops so both
         # maintenance paths face the same oracle
         if t % 2 == 0:
-            state = slide_and_maintain(state, drop, bs, bd, bc, valid, eps=EPS)
+            state = slide_and_maintain(state, expiry, bs, bd, bc, valid,
+                                       eps=EPS)
             state_ws, _ = slide_and_maintain_auto(
                 state_ws, drop, bs, bd, bc, valid, eps=EPS, min_bucket=4
+            )
+            state_pr, _ = slide_and_maintain_predictive(
+                state_pr, expiry, bs, bd, bc, valid, predictor, eps=EPS
             )
         else:
             state = delete_and_maintain(state, drop, eps=EPS)
@@ -244,7 +274,14 @@ def run_window_differential(base, ticks, window):
             state_ws, _ = insert_and_maintain_auto(
                 state_ws, bs, bd, bc, valid, eps=EPS, min_bucket=4
             )
+            state_pr, _ = slide_and_maintain_predictive(
+                state_pr, expiry, zi, zi, zf, zv, predictor, eps=EPS
+            )
+            state_pr, _ = insert_and_maintain_predictive(
+                state_pr, bs, bd, bc, valid, predictor, eps=EPS
+            )
         assert_states_bit_identical(state, state_ws, tag=f"tick{t}")
+        assert_states_bit_identical(state, state_pr, tag=f"pred-tick{t}")
         ring.append(list(batch))
 
         mirror = list(base) + [e for b in ring for e in b]
@@ -313,6 +350,176 @@ def test_window_replay_seeded(seed):
     state = run_window_differential(base, ticks, window=2)
     # window bound: only base + at most 2 ticks of <=4 edges remain
     assert int(state.edge_count) <= len(base) + 2 * 4
+
+
+# ---------------------------------------------------------------------------
+# window expiry: the oldest tick by a span shift, as remove_edges and the
+# [E] prologue would expire it
+# ---------------------------------------------------------------------------
+
+
+def _window_state(seed, m_base, ticks, window, batch, n=40, e_pad=0):
+    """A state whose live edges are a base graph then ``ticks`` resident
+    ticks (their edge counts, oldest first), in the windowed service's
+    buffer of ``m_base + (window + 1) * batch`` slots (plus ``e_pad``),
+    with random levels and a random community so that ``r0`` and the
+    community's loss both depend on what expires."""
+    rng = np.random.default_rng(seed)
+    m = m_base + sum(ticks)
+    src = rng.integers(0, n, m)
+    dst = (src + 1 + rng.integers(0, n - 1, m)) % n
+    c = rng.integers(1, 5, m).astype(np.float32)
+    g = device_graph_from_coo(n, src, dst, c, n_capacity=64,
+                              e_capacity=m_base + (window + 1) * batch
+                              + e_pad)
+    state = init_state(g, eps=EPS)
+    level = np.where(np.asarray(g.vertex_mask), rng.integers(0, 6, 64), -1)
+    comm = np.asarray(g.vertex_mask) & (rng.random(64) < 0.5)
+    state = dataclasses.replace(state, level=jnp.asarray(level, jnp.int32),
+                                community=jnp.asarray(comm))
+    bs = rng.integers(0, n, batch)
+    bd = (bs + 1 + rng.integers(0, n - 1, batch)) % n
+    batch_arrays = (jnp.asarray(bs, jnp.int32), jnp.asarray(bd, jnp.int32),
+                    jnp.asarray(rng.integers(1, 5, batch), jnp.float32),
+                    jnp.asarray(rng.random(batch) < 0.8))
+    return state, batch_arrays
+
+
+def _copy(tree):
+    return jax.tree_util.tree_map(jnp.copy, tree)
+
+
+@pytest.mark.parametrize("m_base, ticks, window, batch, e_pad", [
+    (50, [8, 8, 8], 3, 8, 0),   # a full window of full ticks
+    (50, [8, 8, 5], 3, 8, 0),   # a partial newest tick
+    (37, [3, 8, 8], 3, 8, 0),   # a partial oldest tick
+    (50, [0, 8, 8], 3, 8, 0),   # count = 0: nothing expires
+    (50, [8, 8], 3, 8, 0),      # a window not yet full
+    (0, [8, 8, 8, 8], 4, 8, 0),  # no base graph
+    (61, [7, 8, 8], 3, 8, 59),  # a capacity rounded up past the window
+])
+def test_window_expiry_matches_remove_edges(m_base, ticks, window, batch,
+                                            e_pad):
+    """``expire_window`` against ``remove_edges`` on the same slot mask,
+    and the expiry's bookkeeping against the ``[E]`` prologue's: the
+    same slots, removed count, ``r0``, dropped mass, community loss
+    (in ``prior_g``) and, through phase B and the fused tick, the same
+    ``w0`` decrement and state, bit for bit."""
+    state, (bs, bd, bc, valid) = _window_state(
+        m_base + 7 * len(ticks), m_base, ticks, window, batch, e_pad=e_pad)
+    g = state.graph
+    count = ticks[0]
+    slot = jnp.arange(g.e_capacity)
+    mask = (slot >= m_base) & (slot < m_base + count)
+    expiry = WindowExpiry(jnp.int32(m_base), jnp.int32(count), window * batch)
+
+    want, n_want = remove_edges(g, mask)
+    got, n_got = expire_window(g, expiry)
+    for f in ("src", "dst", "c", "edge_mask"):
+        np.testing.assert_array_equal(np.asarray(getattr(got, f)),
+                                      np.asarray(getattr(want, f)), err_msg=f)
+    assert int(n_got) == int(n_want) == count
+
+    bk_m = _slide_prologue(state, mask, bs, bd, valid)
+    bk_x = _slide_prologue(state, expiry, bs, bd, valid)
+    assert bk_x.cd.shape == (window * batch,)
+    for f in ("r0", "n_new", "keep", "prior_g"):
+        np.testing.assert_array_equal(np.asarray(getattr(bk_x, f)),
+                                      np.asarray(getattr(bk_m, f)), err_msg=f)
+    assert int(jnp.sum(bk_x.dropped)) == int(jnp.sum(bk_m.dropped)) == count
+    assert float(jnp.sum(bk_x.cd)) == float(jnp.sum(bk_m.cd))
+
+    # phase B's merge decrements w0 by the dropped mass, scattered over
+    # every lane or compacted into a bucket of the dropped count
+    g_m = append_edges(want, state.edge_count - n_want, bs, bd, bc, valid)
+    g_x = append_edges(got, state.edge_count - n_got, bs, bd, bc, valid)
+    for d_bucket in (0, select_bucket(count, g.e_capacity, floor=4)):
+        outs = [_phase_b(_copy(state), _copy(gg), bk, n, bs, bd, bc, valid,
+                         eps=EPS, max_rounds=6, d_bucket=d_bucket)
+                for gg, bk, n in ((g_m, bk_m, n_want), (g_x, bk_x, n_got))]
+        assert_states_bit_identical(*outs, tag=f"phase_b d_bucket={d_bucket}")
+    fused = [slide_and_maintain(_copy(state), drop, bs, bd, bc, valid,
+                                eps=EPS, max_rounds=6)
+             for drop in (mask, expiry)]
+    assert_states_bit_identical(*fused, tag="fused")
+
+
+def _spade_dg():
+    """The benchmark's plain DG reference, loaded by its path."""
+    path = Path(__file__).resolve().parents[1] / "bench" / "references" \
+        / "spade_dg.py"
+    spec = importlib.util.spec_from_file_location("spade_dg_ref", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod  # its dataclasses look their module up
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("workset", [False, True],
+                         ids=["fused", "predictive"])
+def test_served_window_matches_the_dg_reference(workset):
+    """A served windowed run under DG answers what the plain reference
+    (``bench/references/spade_dg.py``) answers replaying the same stream:
+    the density, the live and expired edges, the ticks, the benign count,
+    the planted accounts detected and every tick's affected suffix."""
+    stream = make_transaction_stream(n=600, m=3000, seed=2)
+    spec = EngineSpec(batch_edges=32, max_rounds=6, window_ticks=3,
+                      workset=workset, min_bucket=16)
+    rep = SpadeService("DG", spec).run(stream)
+    ref = _spade_dg().SpadeDG(stream.n_vertices, stream.base_src,
+                              stream.base_dst, eps=spec.eps,
+                              max_rounds=spec.max_rounds,
+                              window_ticks=spec.window_ticks)
+    n_inc = stream.inc_src.shape[0]
+    for i in range(0, n_inc, spec.batch_edges):
+        ref.tick(stream.inc_src[i:i + spec.batch_edges],
+                 stream.inc_dst[i:i + spec.batch_edges])
+    want = ref.result()
+    assert rep.n_ticks == want.n_ticks > spec.window_ticks
+    assert rep.final_g == want.final_g
+    assert rep.live_edges == want.live_edges
+    assert rep.n_expired_edges == want.n_expired_edges > 0
+    assert round(rep.benign_fraction * n_inc) == want.benign
+    fraud = set(stream.fraud_block.tolist())
+    assert rep.fraud_recall == len(fraud & want.detected) / len(fraud)
+    assert int(rep.suffix_edges.max()) == want.max_suffix_edges
+    if workset:
+        assert rep.max_suffix_edges == want.max_suffix_edges
+        assert rep.n_workset_ticks + rep.n_fallback_ticks == rep.n_ticks
+
+
+@pytest.mark.parametrize("workset", [False, True],
+                         ids=["fused", "predictive"])
+def test_window_tick_programs_compile_once_per_configuration(workset):
+    """Two base graphs a few edges apart, in buffers of one capacity, go
+    through the served window path: the second compiles nothing, since no
+    static argument or shape of a tick program comes from the base
+    graph's edge count."""
+    stream = make_transaction_stream(n=600, m=3000, seed=2)
+    fewer = dataclasses.replace(stream, base_src=stream.base_src[3:],
+                                base_dst=stream.base_dst[3:],
+                                base_amt=stream.base_amt[3:])
+    spec = EngineSpec(batch_edges=32, max_rounds=6, window_ticks=3,
+                      workset=workset)
+    cap = [-(-(s.base_src.shape[0] + 4 * 32) // 512) * 512
+           for s in (stream, fewer)]
+    assert cap[0] == cap[1]
+    compiled: list[str] = []
+
+    def on_event(event, duration, fun_name="?", **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(fun_name)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        first = SpadeService("DG", spec).run(stream)
+        n_first = len(compiled)
+        second = SpadeService("DG", spec).run(fewer)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert first.n_expired_edges > 0 and second.n_expired_edges > 0
+    assert first.live_edges - second.live_edges == 3
+    assert compiled[n_first:] == []
 
 
 # ---------------------------------------------------------------------------
