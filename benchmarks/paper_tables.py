@@ -390,6 +390,7 @@ def bench_workset(
         ))
         t_fb = timed(lambda: bulk_peel_warm(
             g, kmask, prior_best_g=state_hot.best_g, eps=0.1, max_rounds=20,
+            w_full=state_hot.w0, w_exact=state_hot.w0_exact,
         ))
         rows.append((f"workset_peel_b{be}", t_ws * 1e6,
                      t_fb / max(t_ws, 1e-9)))

@@ -72,7 +72,8 @@ _LEVEL_NEW = jnp.int32(2**31 - 1)
 
 @partial(
     jax.tree_util.register_dataclass,
-    data_fields=["graph", "level", "best_g", "community", "edge_count", "w0"],
+    data_fields=["graph", "level", "best_g", "community", "edge_count", "w0",
+                 "w0_exact"],
     meta_fields=[],
 )
 @dataclasses.dataclass(frozen=True)
@@ -80,7 +81,12 @@ class DeviceSpadeState:
     """Evolving-graph fraud-detection state (pure pytree, donate-friendly).
 
     ``w0[u]`` mirrors the full-graph peeling weight ``w_u(S_0)`` for the
-    O(1) benign/urgent test (Def 4.1).
+    O(1) benign/urgent test (Def 4.1).  ``w0_exact`` holds while every
+    weight summed into ``w0`` was a non-negative integer and no sum passed
+    2^24: each f32 sum was then exact, in any order, so ``w0`` is a fresh
+    sum's bits and the warm re-peel may read its seed from it
+    (:func:`_next_w0`).  Once cleared it stays cleared until a refresh
+    sums ``w0`` afresh.
     """
 
     graph: DeviceGraph
@@ -89,18 +95,38 @@ class DeviceSpadeState:
     community: jax.Array  # bool [V_cap] — maintained S^P
     edge_count: jax.Array  # int32 scalar — next free edge slot
     w0: jax.Array  # float32 [V_cap]
+    w0_exact: jax.Array  # bool scalar
+
+
+_EXACT_LIMIT = float(2**24)
+
+
+def _integral(x: jax.Array) -> jax.Array:
+    """Elementwise: ``x`` is a non-negative integer of at most 2^24, so f32
+    sums of such values, while they stay at most 2^24, are exact."""
+    return (x >= 0) & (x == jnp.floor(x)) & (x <= _EXACT_LIMIT)
+
+
+def _exact_weights(g: DeviceGraph, w0: jax.Array) -> jax.Array:
+    """``DeviceSpadeState.w0_exact`` of ``w0 = g.peel_weights()`` (or any
+    sum of the same weights): every live weight and every sum integral."""
+    return (jnp.all(~g.edge_mask | _integral(g.c))
+            & jnp.all(~g.vertex_mask | _integral(g.a))
+            & jnp.all(_integral(w0)))
 
 
 def init_state(g: DeviceGraph, eps: float = 0.1) -> DeviceSpadeState:
     """Static bulk peel (Algorithm 1, bulk form) to seed the state."""
     res = bulk_peel(g, eps=eps)
+    w0 = g.peel_weights()
     return DeviceSpadeState(
         graph=g,
         level=res.level,
         best_g=res.best_g,
         community=res.community_mask() & g.vertex_mask,
         edge_count=jnp.sum(g.edge_mask).astype(jnp.int32),
-        w0=g.peel_weights(),
+        w0=w0,
+        w0_exact=_exact_weights(g, w0),
     )
 
 
@@ -187,21 +213,30 @@ def _slide_prologue(
     )
 
 
-def _slide_epilogue(
+def _next_w0(
     state: DeviceSpadeState,
-    g: DeviceGraph,
-    res: PeelResultDevice,
     bk: _SlideBookkeeping,
-    n_removed: jax.Array,
     src, dst, c, valid,
     with_drops: bool = True,
     d_bucket: int = 0,
-) -> DeviceSpadeState:
-    """Merge a warm re-peel back into the state (level rebase, community
-    update, exact w0 decrement/increment, edge-counter move).
+) -> tuple[jax.Array, jax.Array]:
+    """The full graph's peel weights after the tick, ``(w0, w0_exact)``:
+    ``state.w0`` less the dropped edges' mass, plus the batch's valid
+    lanes, on both endpoints, and whether they are still exact.
+
+    One definition for the merge's ``w0`` and the warm re-peel's seed
+    (:func:`repro.core.peel.bulk_peel_warm`).  It is ``peel_weights`` of
+    the post-tick graph while every valid lane was appended, which
+    callers guarantee by sizing the buffer; padding lanes carry ``cd = 0``
+    / ``cv = 0``.  While ``w0_exact`` holds (every batch weight a
+    non-negative integer, every weight at most 2^24: DG) the decrement
+    and increment are exact, so ``w0`` is a fresh sum's bits.  Otherwise
+    each tick's adds and subtracts round, and the rounding walks with
+    the stream, sized to the largest weight a vertex has held; the seed
+    then sums afresh, and only the benign test reads ``w0``.
 
     ``with_drops = False`` (insert-only ticks) statically elides the
-    dropped-mass w0 decrement, restoring in-place donation of the edge
+    dropped-mass decrement, restoring in-place donation of the edge
     buffers (the decrement gathers pre-update ``src/dst``, which otherwise
     blocks XLA from reusing them for the appended graph).
 
@@ -211,18 +246,8 @@ def _slide_epilogue(
     scatter-adds O(dropped) updates instead of one per drop-mask lane —
     on a steady-state tick the dropped batch is ~1k lanes of a ~400k
     buffer.  A :class:`WindowExpiry`'s lanes are the window's span alone.
-    Identical sums on integer weights; scatter-add order may differ
-    otherwise (the same reduction-order caveat as the sharded engine)."""
+    """
     g0 = state.graph
-    suffix_level = jnp.where(res.level >= 0, res.level, res.n_rounds)
-    new_level = jnp.where(bk.keep, bk.r0 + suffix_level, state.level)
-    improved = res.best_g > bk.prior_g
-    new_comm = jnp.where(
-        improved,
-        (res.level >= res.best_level) & bk.keep & g.vertex_mask,
-        state.community,
-    )
-    # exact on integer weights; padding lanes carry cd = 0 / cv = 0
     w0 = state.w0
     d_src = g0.src if bk.d_src is None else bk.d_src
     d_dst = g0.dst if bk.d_dst is None else bk.d_dst
@@ -245,6 +270,31 @@ def _slide_epilogue(
     cv = jnp.where(valid, c.astype(jnp.float32), 0.0)
     w0 = w0.at[src].add(cv, mode="drop")
     w0 = w0.at[dst].add(cv, mode="drop")
+    exact = (state.w0_exact & jnp.all(~valid | _integral(cv))
+             & jnp.all(_integral(w0)))
+    return w0, exact
+
+
+def _slide_epilogue(
+    state: DeviceSpadeState,
+    g: DeviceGraph,
+    res: PeelResultDevice,
+    bk: _SlideBookkeeping,
+    n_removed: jax.Array,
+    w0: jax.Array,
+    w0_exact: jax.Array,
+) -> DeviceSpadeState:
+    """Merge a warm re-peel back into the state (level rebase, community
+    update, edge-counter move); ``(w0, w0_exact)`` is the tick's
+    :func:`_next_w0`."""
+    suffix_level = jnp.where(res.level >= 0, res.level, res.n_rounds)
+    new_level = jnp.where(bk.keep, bk.r0 + suffix_level, state.level)
+    improved = res.best_g > bk.prior_g
+    new_comm = jnp.where(
+        improved,
+        (res.level >= res.best_level) & bk.keep & g.vertex_mask,
+        state.community,
+    )
     return DeviceSpadeState(
         graph=g,
         level=new_level,
@@ -252,14 +302,18 @@ def _slide_epilogue(
         community=new_comm,
         edge_count=state.edge_count - n_removed + bk.n_new,
         w0=w0,
+        w0_exact=w0_exact,
     )
 
 
 def tick_counters(counts: np.ndarray, max_rounds: int) -> dict:
-    """Split the fused tick's counter vectors, stacked ``[n_ticks, 1 + 3 *
+    """Split the fused tick's counter vectors, stacked ``[n_ticks, 2 + 3 *
     max_rounds]`` on the host, into named ``int64`` arrays.
 
     ``suffix_r0``: the tick's affected-suffix start ``r0``;
+    ``seed_reused``: 1 where the warm re-peel read its seed from the
+    maintained weights (the suffix held every live vertex and the weights
+    were exact, :func:`repro.core.peel._warm_seed`), 0 where it summed it;
     ``round_vertices[t, i]`` / ``round_edges[t, i]``: active vertices and
     live edges of the restricted set at the start of round ``i``;
     ``round_slots[t, i]``: the edge slots round ``i`` streamed (0 where no
@@ -267,36 +321,40 @@ def tick_counters(counts: np.ndarray, max_rounds: int) -> dict:
     whole suffix: ``suffix_vertices`` and ``suffix_edges`` (the suffix's
     induced live edges) are its column.
     """
-    counts = np.asarray(counts, np.int64).reshape(-1, 1 + 3 * max_rounds)
-    rv, re, rs = np.split(counts[:, 1:], 3, axis=1)
-    return {"suffix_r0": counts[:, 0], "suffix_vertices": rv[:, 0].copy(),
+    counts = np.asarray(counts, np.int64).reshape(-1, 2 + 3 * max_rounds)
+    rv, re, rs = np.split(counts[:, 2:], 3, axis=1)
+    return {"suffix_r0": counts[:, 0], "seed_reused": counts[:, 1],
+            "suffix_vertices": rv[:, 0].copy(),
             "suffix_edges": re[:, 0].copy(), "round_vertices": rv,
             "round_edges": re, "round_slots": rs}
 
 
-def _merge(state, g, res, bk, n_removed, src, dst, c, valid, counters,
-           **epilogue):
-    """A re-peel's merge into the state (scope ``tick_merge``).  With
-    ``counters``, ``res`` is ``(result, rounds)`` and the tick's int32
-    counter vector ``[r0, round_vertices..., round_edges...,
+def _merge(state, g, res, bk, n_removed, w_next, counters):
+    """A re-peel's merge into the state (scope ``tick_merge``); ``w_next``
+    is the tick's :func:`_next_w0`, computed before the re-peel under the
+    same scope, since the seed reads it.  With
+    ``counters``, ``res`` is ``(result, counts)`` and the tick's int32
+    counter vector ``[r0, seed_reused, round_vertices..., round_edges...,
     round_slots...]`` comes back beside the state."""
     if counters:
-        res, rounds = res
+        res, counts = res
     with jax.named_scope("tick_merge"):
-        new = _slide_epilogue(state, g, res, bk, n_removed, src, dst, c,
-                              valid, **epilogue)
+        new = _slide_epilogue(state, g, res, bk, n_removed, *w_next)
     if not counters:
         return new
-    return new, jnp.concatenate([bk.r0.astype(jnp.int32)[None], *rounds])
+    return new, jnp.concatenate([bk.r0.astype(jnp.int32)[None], *counts])
 
 
 def _warm_and_merge(state, g, bk, n_removed, src, dst, c, valid, eps,
                     max_rounds, counters, with_drops=True):
     """The fused tick's warm re-peel and merge (see :func:`_merge`)."""
+    with jax.named_scope("tick_merge"):
+        w0, exact = _next_w0(state, bk, src, dst, c, valid,
+                             with_drops=with_drops)
     res = bulk_peel_warm(g, bk.keep, prior_best_g=bk.prior_g, eps=eps,
-                         max_rounds=max_rounds, counters=counters)
-    return _merge(state, g, res, bk, n_removed, src, dst, c, valid, counters,
-                  with_drops=with_drops)
+                         max_rounds=max_rounds, counters=counters, w_full=w0,
+                         w_exact=exact)
+    return _merge(state, g, res, bk, n_removed, (w0, exact), counters)
 
 
 def _expire(g: DeviceGraph, drop) -> tuple[DeviceGraph, jax.Array]:
@@ -504,6 +562,9 @@ def _phase_b(
     """Warm re-peel + state merge.  ``v_bucket/e_bucket = 0`` selects the
     full-buffer fallback; otherwise the bucketed workset path.  With
     ``counters``, ``(state, counts)`` as :func:`_merge` says."""
+    with jax.named_scope("tick_merge"):
+        w0, exact = _next_w0(state, bk, src, dst, c, valid,
+                             with_drops=with_drops, d_bucket=d_bucket)
     if v_bucket and e_bucket:
         res = bulk_peel_warm_workset(
             g, bk.keep, prior_best_g=bk.prior_g, eps=eps, max_rounds=max_rounds,
@@ -513,9 +574,8 @@ def _phase_b(
     else:
         res = bulk_peel_warm(g, bk.keep, prior_best_g=bk.prior_g, eps=eps,
                              max_rounds=max_rounds, use_kernel=use_kernel,
-                             counters=counters)
-    return _merge(state, g, res, bk, n_removed, src, dst, c, valid, counters,
-                  with_drops=with_drops, d_bucket=d_bucket)
+                             counters=counters, w_full=w0, w_exact=exact)
+    return _merge(state, g, res, bk, n_removed, (w0, exact), counters)
 
 
 def _unpack(out, counters: bool):
@@ -682,13 +742,15 @@ def _phase_b_checked(
     onto the device (``lax.cond`` on the actual counts), so dispatch needs
     no host-resident count.  Returns ``(out, fits)``, ``out`` as
     :func:`_phase_b`'s."""
+    with jax.named_scope("tick_merge"):
+        w0, exact = _next_w0(state, bk, src, dst, c, valid,
+                             with_drops=with_drops, d_bucket=d_bucket)
     res, fits = bulk_peel_warm_checked(
         g, bk.keep, bk.prior_g, nv, ne, eps=eps, max_rounds=max_rounds,
         v_bucket=v_bucket, e_bucket=e_bucket, use_kernel=use_kernel,
-        counters=counters,
+        counters=counters, w_full=w0, w_exact=exact,
     )
-    return _merge(state, g, res, bk, n_removed, src, dst, c, valid, counters,
-                  with_drops=with_drops, d_bucket=d_bucket), fits
+    return _merge(state, g, res, bk, n_removed, (w0, exact), counters), fits
 
 
 def _predictive_dispatch_core(
@@ -823,13 +885,15 @@ def slide_and_maintain_predictive(
 def full_refresh(state: DeviceSpadeState, eps: float = 0.1) -> DeviceSpadeState:
     """Periodic from-scratch bulk peel (compaction / drift control)."""
     res = bulk_peel(state.graph, eps=eps)
+    w0 = state.graph.peel_weights()
     return DeviceSpadeState(
         graph=state.graph,
         level=res.level,
         best_g=res.best_g,
         community=res.community_mask() & state.graph.vertex_mask,
         edge_count=state.edge_count,
-        w0=state.graph.peel_weights(),
+        w0=w0,
+        w0_exact=_exact_weights(state.graph, w0),
     )
 
 
@@ -843,4 +907,5 @@ def admit_vertices(state: DeviceSpadeState, ids: jax.Array, a: jax.Array) -> Dev
         graph=dataclasses.replace(g, vertex_mask=vm, a=av),
         level=state.level.at[ids].set(_LEVEL_NEW, mode="drop"),
         w0=state.w0.at[ids].set(a.astype(jnp.float32), mode="drop"),
+        w0_exact=state.w0_exact & jnp.all(_integral(a.astype(jnp.float32))),
     )

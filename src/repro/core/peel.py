@@ -485,6 +485,65 @@ def _run_stages(src, dst, c, a, eps, use_kernel, init, max_rounds,
     return s, (counts if counters else None)
 
 
+def _seed_by_sums(g: DeviceGraph, live: jax.Array):
+    """The restricted set's ``(w, edge_alive, f)`` recovered from the edge
+    buffer: two ``[E]`` gathers mark the edges with both endpoints in
+    ``live``, and two ``[E]``-to-``[V]`` segment sums add their mass to the
+    endpoints' weights."""
+    V = g.n_capacity
+    both = live[g.src] & live[g.dst] & g.edge_mask
+    cm = jnp.where(both, g.c, 0.0)
+    w0 = jnp.where(live, g.a, 0.0)
+    w0 = w0 + jax.ops.segment_sum(cm, g.src, num_segments=V)
+    w0 = w0 + jax.ops.segment_sum(cm, g.dst, num_segments=V)
+    f0 = jnp.sum(jnp.where(live, g.a, 0.0)) + jnp.sum(cm)
+    return w0, both, f0
+
+
+def _seed_from_weights(g: DeviceGraph, live: jax.Array, w_full: jax.Array):
+    """The seed of a set that holds every live vertex, read from the full
+    graph's peel weights ``w_full`` (``DeviceGraph.peel_weights`` of ``g``).
+
+    A live edge has live endpoints (the invariant :func:`bulk_peel` and
+    ``peel_weights`` rest on), so every live edge is induced by the set:
+    ``edge_alive`` is ``edge_mask``, each weight is the full graph's, and
+    ``f`` is the same two reductions :func:`_seed_by_sums` makes over the
+    same values (:func:`_set_mass`).  No ``[E]`` gather or segment sum
+    runs."""
+    return (jnp.where(live, w_full, 0.0), g.edge_mask,
+            _set_mass(live, g.a, g.edge_mask, g.c))
+
+
+def _warm_seed(g: DeviceGraph, keep: jax.Array, w_full: jax.Array,
+               w_exact: jax.Array):
+    """The warm re-peel's start on ``keep``: ``(live, w, edge_alive, f,
+    reused)``, the set's live vertices, their weights and the induced live
+    edges within the set, its mass, and whether the seed was read from
+    ``w_full``.
+
+    ``w_full`` is the full graph's peel weights as the caller keeps them
+    (the incremental state's ``w0`` after the tick's batch), and
+    ``w_exact`` says they equal a fresh sum: every weight ever added to
+    them was a non-negative integer and no sum passed 2^24, so each f32
+    sum was exact in any order (``core.incremental._next_w0``).  Where
+    both hold and the set holds every live vertex (on Grab4 under DG
+    every tick: a batch always touches a level-0 account), ``lax.cond``
+    reads the seed from ``w_full`` (:func:`_seed_from_weights`), and it
+    is bit for bit the segment sums' seed, as is every round after it.
+    Otherwise the seed is summed from the edge buffer
+    (:func:`_seed_by_sums`).  Non-integer weights take that path on
+    every tick: added to and taken from on every tick, maintained f32
+    weights carry rounding that grows with the stream, sized to the
+    largest weight a vertex has held.
+    """
+    live = keep & g.vertex_mask
+    reuse = jnp.all(keep | ~g.vertex_mask) & w_exact
+    w0, alive, f0 = jax.lax.cond(
+        reuse, partial(_seed_from_weights, g, live, w_full),
+        partial(_seed_by_sums, g, live))
+    return live, w0, alive, f0, reuse
+
+
 def bulk_peel_warm(
     g: DeviceGraph,
     keep: jax.Array,
@@ -493,6 +552,9 @@ def bulk_peel_warm(
     max_rounds: int = 0,
     use_kernel: bool = False,
     counters: bool = False,
+    *,
+    w_full: jax.Array,
+    w_exact: jax.Array,
 ):
     """Bulk peel restricted to ``keep`` vertices (warm start).
 
@@ -501,6 +563,10 @@ def bulk_peel_warm(
     restricted set, so every round's threshold is valid on the current set
     and the 2(1+eps) guarantee is preserved (DESIGN.md §2).  ``prior_best_g``
     seeds the best-density tracker so the maintained best never regresses.
+    ``w_full`` and ``w_exact``, the full graph's peel weights and whether
+    they are exact, let a set that holds every live vertex skip the
+    recovery (:func:`_warm_seed`); a caller that keeps no weights passes
+    ``w_exact = False``, and the seed is summed.
 
     This is the **full-buffer** warm path: it starts from the
     capacity-padded ``[E]`` buffer and compacts the live edges down a
@@ -510,23 +576,19 @@ def bulk_peel_warm(
     bucketed buffers before the first round; this function is the
     fallback when the suffix exceeds the largest bucket (DESIGN.md §8).
 
-    ``counters`` (a bounded peel only) returns ``(result, (round_vertices,
-    round_edges, round_slots))``: see :func:`_run_stages`.
+    ``counters`` (a bounded peel only) returns ``(result, (seed_reused,
+    round_vertices, round_edges, round_slots))``: ``seed_reused`` int32
+    ``[1]``, 1 where the seed was read from ``w_full``, then the rounds'
+    counters of :func:`_run_stages`.
     """
     V = g.n_capacity
     with jax.named_scope("tick_seed"):
-        live = keep & g.vertex_mask
-        both = live[g.src] & live[g.dst] & g.edge_mask
-        cm = jnp.where(both, g.c, 0.0)
-        w0 = jnp.where(live, g.a, 0.0)
-        w0 = w0 + jax.ops.segment_sum(cm, g.src, num_segments=V)
-        w0 = w0 + jax.ops.segment_sum(cm, g.dst, num_segments=V)
-        f0 = jnp.sum(jnp.where(live, g.a, 0.0)) + jnp.sum(cm)
+        live, w0, alive, f0, reused = _warm_seed(g, keep, w_full, w_exact)
 
     init = _BulkState(
         w=w0,
         active=live,
-        edge_alive=both,
+        edge_alive=alive,
         f=f0,
         n_act=jnp.sum(live),
         level=jnp.full(V, -1, jnp.int32),
@@ -545,7 +607,9 @@ def bulk_peel_warm(
         order=jnp.zeros(V, jnp.int32),
         delta=state.w,
     )
-    return (res, counts) if counters else res
+    if not counters:
+        return res
+    return res, (reused.astype(jnp.int32)[None], *counts)
 
 
 # ---------------------------------------------------------------------------
@@ -694,7 +758,8 @@ def bulk_peel_warm_workset(
     argument across the scatter-back.  The rounds are :func:`_run_stages`
     over the bucket, so they stop once the set is empty, and
     ``counters`` returns ``(result, counts)`` as :func:`bulk_peel_warm`
-    does, with the bucket's ladder in ``round_slots``.
+    does, with the bucket's ladder in ``round_slots`` and ``seed_reused``
+    0 (the workset sums its own seed).
     """
     V = g.n_capacity
     ws = _gather_workset(g, keep, v_bucket, e_bucket)
@@ -728,7 +793,9 @@ def bulk_peel_warm_workset(
         order=jnp.zeros(V, jnp.int32),
         delta=delta,
     )
-    return (res, counts) if counters else res
+    if not counters:
+        return res
+    return res, (jnp.zeros(1, jnp.int32), *counts)
 
 
 @partial(
@@ -749,6 +816,8 @@ def bulk_peel_warm_checked(
     e_bucket: int,
     use_kernel: bool = False,
     counters: bool = False,
+    w_full: jax.Array,
+    w_exact: jax.Array,
 ):
     """Warm peel with a *device-side* bucket-fit check — the primitive the
     predictive workset dispatcher builds on.
@@ -766,7 +835,9 @@ def bulk_peel_warm_checked(
 
     Returns ``(result, fits)`` with ``fits`` the device bool the caller
     can drain lazily for telemetry; with ``counters``, ``result`` is
-    ``(result, counts)`` from whichever branch ran.
+    ``(result, counts)`` from whichever branch ran.  ``w_full`` and
+    ``w_exact`` reach the full-buffer branch's seed
+    (:func:`bulk_peel_warm`).
     """
     fits = (nv <= jnp.int32(v_bucket)) & (ne <= jnp.int32(e_bucket))
     res = jax.lax.cond(
@@ -778,7 +849,8 @@ def bulk_peel_warm_checked(
         ),
         lambda: bulk_peel_warm(
             g, keep, prior_best_g, eps=eps, max_rounds=max_rounds,
-            use_kernel=use_kernel, counters=counters,
+            use_kernel=use_kernel, counters=counters, w_full=w_full,
+            w_exact=w_exact,
         ),
     )
     return res, fits

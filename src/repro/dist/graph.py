@@ -63,6 +63,8 @@ from repro.core.incremental import (
     BucketPredictor,
     DeviceSpadeState,
     WorksetTickInfo,
+    _exact_weights,
+    _next_w0,
     _predictive_dispatch_core,
     _slide_epilogue,
     _slide_prologue,
@@ -500,13 +502,15 @@ def init_sharded_state(
     """Sharded twin of :func:`repro.core.incremental.init_state`; ``g``
     should come from :func:`shard_graph`."""
     res = sharded_bulk_peel(g, mesh, axis=axis, eps=eps)
+    w0 = sharded_peel_weights(g, mesh, axis=axis)
     return DeviceSpadeState(
         graph=g,
         level=res.level,
         best_g=res.best_g,
         community=res.community_mask() & g.vertex_mask,
         edge_count=jnp.sum(g.edge_mask).astype(jnp.int32),
-        w0=sharded_peel_weights(g, mesh, axis=axis),
+        w0=w0,
+        w0_exact=_exact_weights(g, w0),
     )
 
 
@@ -542,8 +546,9 @@ def sharded_insert_and_maintain(
     res = _sharded_peel(
         g, bk.keep, bk.prior_g, mesh, axis, eps, max_rounds, warm=True
     )
-    return _slide_epilogue(state, g, res, bk, jnp.int32(0), src, dst, c, valid,
-                           with_drops=False)
+    return _slide_epilogue(
+        state, g, res, bk, jnp.int32(0),
+        *_next_w0(state, bk, src, dst, c, valid, with_drops=False))
 
 
 def sharded_delete_and_maintain(
@@ -596,7 +601,8 @@ def sharded_slide_and_maintain(
     res = _sharded_peel(
         g, bk.keep, bk.prior_g, mesh, axis, eps, max_rounds, warm=True
     )
-    return _slide_epilogue(state, g, res, bk, n_removed, src, dst, c, valid)
+    return _slide_epilogue(state, g, res, bk, n_removed,
+                           *_next_w0(state, bk, src, dst, c, valid))
 
 
 # ---------------------------------------------------------------------------
@@ -651,8 +657,10 @@ def _sharded_phase_b(
         res = _sharded_peel(
             g, bk.keep, bk.prior_g, mesh, axis, eps, max_rounds, warm=True
         )
-    return _slide_epilogue(state, g, res, bk, n_removed, src, dst, c, valid,
-                           with_drops=with_drops, d_bucket=d_bucket)
+    return _slide_epilogue(
+        state, g, res, bk, n_removed,
+        *_next_w0(state, bk, src, dst, c, valid, with_drops=with_drops,
+                 d_bucket=d_bucket))
 
 
 def _sharded_dispatch_phase_b(
@@ -768,8 +776,10 @@ def _sharded_phase_b_checked(
             g, bk.keep, bk.prior_g, mesh, axis, eps, max_rounds, warm=True
         ),
     )
-    return _slide_epilogue(state, g, res, bk, n_removed, src, dst, c, valid,
-                           with_drops=with_drops, d_bucket=d_bucket), fits
+    return _slide_epilogue(
+        state, g, res, bk, n_removed,
+        *_next_w0(state, bk, src, dst, c, valid, with_drops=with_drops,
+                 d_bucket=d_bucket)), fits
 
 
 def _sharded_predictive_dispatch(
@@ -854,11 +864,13 @@ def sharded_full_refresh(
 ) -> DeviceSpadeState:
     """Edge-sharded twin of :func:`repro.core.incremental.full_refresh`."""
     res = sharded_bulk_peel(state.graph, mesh, axis=axis, eps=eps)
+    w0 = sharded_peel_weights(state.graph, mesh, axis=axis)
     return DeviceSpadeState(
         graph=state.graph,
         level=res.level,
         best_g=res.best_g,
         community=res.community_mask() & state.graph.vertex_mask,
         edge_count=state.edge_count,
-        w0=sharded_peel_weights(state.graph, mesh, axis=axis),
+        w0=w0,
+        w0_exact=_exact_weights(state.graph, w0),
     )

@@ -529,13 +529,13 @@ def _spade_cells(arch, cfg: SpadeConfig, spec: ShapeSpec, concrete, rng) -> Cell
         state = DeviceSpadeState(
             graph=g, level=_sds((g.n_capacity,), _i32), best_g=_sds((), _f32),
             community=_sds((g.n_capacity,), _b), edge_count=_sds((), _i32),
-            w0=_sds((g.n_capacity,), _f32),
+            w0=_sds((g.n_capacity,), _f32), w0_exact=_sds((), _b),
         )
         bs = bd = _sds((B,), _i32)
         bc = _sds((B,), _f32)
         valid = _sds((B,), _b)
     sl = DeviceSpadeState(graph=gl, level=(None,), best_g=(), community=(None,),
-                          edge_count=(), w0=(None,))
+                          edge_count=(), w0=(None,), w0_exact=())
     return Cell(arch, spec.name, "spade", "insert_and_maintain", fn,
                 (state, bs, bd, bc, valid),
                 (sl, (None,), (None,), (None,), (None,)), sl,

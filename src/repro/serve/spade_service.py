@@ -192,10 +192,14 @@ class DeviceServiceReport:
     # and the suffix's vertices and induced live edges (round 0),
     # [n_ticks]; ``edge_slots`` is the edge buffer's capacity, which the
     # first stage of a fused or fallback tick's rounds streams.
+    # ``seed_reused``, [n_ticks]: 1 where the tick's warm re-peel read its
+    # seed from the maintained weights (the suffix held every live
+    # vertex), 0 where it summed it from the edge buffer or the workset.
     round_vertices: np.ndarray | None = None
     round_edges: np.ndarray | None = None
     round_slots: np.ndarray | None = None
     suffix_r0: np.ndarray | None = None
+    seed_reused: np.ndarray | None = None
     suffix_vertices: np.ndarray | None = None
     suffix_edges: np.ndarray | None = None
     edge_slots: int | None = None
@@ -480,7 +484,7 @@ def _run_device_service(
         counted_fields = {}
         if counted and len(counts) == n_ticks:  # a count for every tick
             rows = np.stack(jax.device_get(counts)) if counts \
-                else np.zeros((0, 1 + 3 * max_rounds), np.int64)
+                else np.zeros((0, 2 + 3 * max_rounds), np.int64)
             counted_fields = dict(tick_counters(rows, max_rounds),
                                   edge_slots=int(g.e_capacity))
     fraud = set(stream.fraud_block.tolist())

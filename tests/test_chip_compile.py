@@ -89,6 +89,7 @@ def _state(edge_sh, vert_sh) -> DeviceSpadeState:
         community=_sds((V,), jnp.bool_, vert_sh),
         edge_count=_sds((), jnp.int32, vert_sh),
         w0=_sds((V,), jnp.float32, vert_sh),
+        w0_exact=_sds((), jnp.bool_, vert_sh),
     )
 
 

@@ -202,8 +202,10 @@ def test_bulk_peel_kernel_wired_round_parity():
         assert int(got.n_rounds) == int(ref.n_rounds)
 
     keep_mask = jnp.asarray(np.asarray(ref.level) >= 2)
-    wref = bulk_peel_warm(g, keep_mask, prior_best_g=ref.best_g, eps=0.1)
+    seed = dict(w_full=g.peel_weights(), w_exact=jnp.bool_(False))
+    wref = bulk_peel_warm(g, keep_mask, prior_best_g=ref.best_g, eps=0.1,
+                          **seed)
     wgot = bulk_peel_warm(g, keep_mask, prior_best_g=ref.best_g, eps=0.1,
-                          use_kernel=True)
+                          use_kernel=True, **seed)
     np.testing.assert_array_equal(np.asarray(wgot.level), np.asarray(wref.level))
     assert float(wgot.best_g) == float(wref.best_g)

@@ -125,11 +125,16 @@ def test_counters_match_numpy_recount(kind, max_rounds, width):
             state, cnt = insert_and_maintain(
                 state, *args, eps=EPS, max_rounds=max_rounds, counters=True)
         cnt = np.asarray(cnt)
-        assert cnt.dtype == np.int32 and cnt.shape == (1 + 3 * max_rounds,)
+        assert cnt.dtype == np.int32 and cnt.shape == (2 + 3 * max_rounds,)
         r0, rv, re = _numpy_recount(level0, state.graph, src_b, dst_b,
                                     valid, drop_ends, max_rounds)
         got = tick_counters(cnt[None], max_rounds)
         assert int(got["suffix_r0"][0]) == r0
+        # the seed is read from the maintained weights exactly where the
+        # suffix holds every live vertex
+        vmask = np.asarray(state.graph.vertex_mask)
+        whole = bool(np.all((level0 >= r0) | ~vmask))
+        assert int(got["seed_reused"][0]) == int(whole)
         assert got["round_vertices"][0].tolist() == rv
         assert got["round_edges"][0].tolist() == re
         assert int(got["suffix_vertices"][0]) == rv[0]
@@ -149,6 +154,41 @@ def test_counters_match_numpy_recount(kind, max_rounds, width):
         assert rv[-1] == 0 and re[-1] == 0
     else:
         assert rv[-1] > 0
+
+
+def test_tick_counters_names_the_counter_vector():
+    """The vector ``[r0, seed_reused, round_vertices..., round_edges...,
+    round_slots...]`` of each tick, split by name; no tick gives arrays of
+    no rows."""
+    max_rounds = 3
+    rows = np.array([[0, 1, 9, 4, 0, 20, 6, 0, 64, 64, 0],
+                     [2, 0, 5, 0, 0, 8, 0, 0, 64, 0, 0]], np.int32)
+    got = tick_counters(rows, max_rounds)
+    assert got["suffix_r0"].tolist() == [0, 2]
+    assert got["seed_reused"].tolist() == [1, 0]
+    assert got["round_vertices"].tolist() == [[9, 4, 0], [5, 0, 0]]
+    assert got["round_edges"].tolist() == [[20, 6, 0], [8, 0, 0]]
+    assert got["round_slots"].tolist() == [[64, 64, 0], [64, 0, 0]]
+    assert got["suffix_vertices"].tolist() == [9, 5]
+    assert got["suffix_edges"].tolist() == [20, 8]
+    empty = tick_counters(np.zeros((0, 2 + 3 * max_rounds)), max_rounds)
+    assert empty["seed_reused"].shape == (0,)
+    assert empty["round_slots"].shape == (0, max_rounds)
+
+
+def test_a_run_of_no_ticks_reports_empty_counters():
+    """A stream with nothing to serve: the report's counters hold no rows,
+    in the counter vector's shape."""
+    stream = make_transaction_stream(n=600, m=3000, seed=2)
+    stream = dataclasses.replace(
+        stream, inc_src=stream.inc_src[:0], inc_dst=stream.inc_dst[:0],
+        inc_amt=stream.inc_amt[:0], inc_time=stream.inc_time[:0],
+        fraud_label=stream.fraud_label[:0])
+    rep = SpadeService("DG", EngineSpec(batch_edges=128,
+                                        max_rounds=6)).run(stream)
+    assert rep.n_ticks == 0
+    assert rep.seed_reused.shape == rep.suffix_r0.shape == (0,)
+    assert rep.round_vertices.shape == rep.round_slots.shape == (0, 6)
 
 
 def test_counters_off_returns_the_state_alone():
@@ -240,6 +280,8 @@ def test_served_loop_spans_in_a_profiler_trace(tmp_path):
             "spade.prep", "spade.weigh", "spade.drain"} <= names
     # the fused engine's counters ride in the same report
     assert rep.round_vertices.shape == (rep.n_ticks, 6)
+    assert rep.seed_reused.shape == (rep.n_ticks,)
+    assert set(rep.seed_reused.tolist()) <= {0, 1}
     assert rep.edge_slots > 0
 
 
@@ -283,6 +325,12 @@ def test_predictive_counters_match_the_fused_engine(stream_kw, window):
     assert ((rs > 0) == ran).all()
     on_bucket = (rs != fused.round_slots).any(axis=1)
     assert on_bucket.sum() == pred.n_workset_ticks
+    # a workset tick sums its own seed; a fallback seeds as the fused does
+    assert (pred.seed_reused[on_bucket] == 0).all()
+    np.testing.assert_array_equal(pred.seed_reused[~on_bucket],
+                                  fused.seed_reused[~on_bucket])
+    if not pred.n_workset_ticks:
+        assert fused.seed_reused.any()
     for row, r in zip(rs[on_bucket], ran[on_bucket]):
         bucket = int(row[0])
         assert bucket & (bucket - 1) == 0 and bucket <= pred.edge_slots // 2

@@ -560,7 +560,8 @@ def test_workset_warm_peel_bucket_boundaries(kn):
     bv = select_bucket(int(nv), g.n_capacity, floor=FLOOR)
     be = select_bucket(int(ne), g.e_capacity, floor=FLOOR)
     assert bv is not None and be is not None
-    full = bulk_peel_warm(g, keep, prior_best_g=res0.best_g, eps=EPS)
+    full = bulk_peel_warm(g, keep, prior_best_g=res0.best_g, eps=EPS,
+                          w_full=g.peel_weights(), w_exact=jnp.bool_(False))
     ws = bulk_peel_warm_workset(
         g, keep, prior_best_g=res0.best_g, eps=EPS, v_bucket=bv, e_bucket=be
     )
